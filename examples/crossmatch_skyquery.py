@@ -9,6 +9,7 @@ reports both scheduling metrics and actual match results.
 """
 import argparse
 
+import jax
 import numpy as np
 
 from repro.core import (
@@ -17,16 +18,19 @@ from repro.core import (
     HybridPlanner,
     LifeRaftScheduler,
 )
+from repro.compile_cache import enable_compile_cache
 from repro.crossmatch import CrossMatchEngine, TraceConfig, make_catalog, make_trace
 
 
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--pallas", action="store_true",
-                    help="run the Pallas kernel (interpret mode) instead of jnp")
+                    help="run the Pallas kernel instead of jnp (compiled on a "
+                         "TPU, interpreted on the CPU)")
     ap.add_argument("--queries", type=int, default=60)
     ap.add_argument("--alpha", type=float, default=0.25)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cat = make_catalog(n_objects=40_000, objects_per_bucket=400, htm_level=8, seed=3)
     trace = make_trace(
@@ -47,8 +51,8 @@ def main():
         hybrid=hybrid,
         use_pallas=args.pallas,
     )
-    print(f"running {len(trace)} cross-match queries "
-          f"({'pallas-interpret' if args.pallas else 'jnp'} join path)...")
+    path = f"pallas on {jax.default_backend()}" if args.pallas else "jnp"
+    print(f"running {len(trace)} cross-match queries ({path} join path)...")
     results = engine.run(trace)
     n_matches = sum(len(r.probe_idx) for groups in results.values() for r in groups)
     s = engine.summary()

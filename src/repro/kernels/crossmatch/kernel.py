@@ -13,6 +13,16 @@ MXU matmul is tile-aligned; M and N are padded to block multiples by the
 ``ops`` wrapper.  Grid = (M/bm, N/bn) with the N dimension innermost and
 "arbitrary" semantics: each probe-tile's outputs are revisited across
 bucket tiles and accumulated with a running max / count.
+
+Per-row operands and outputs are 2-D: a per-probe vector is an (M, 1)
+column (block (bm, 1), sublane-major like the row reductions of the
+(bm, bn) dots tile) and a per-bucket-row vector is a (1, N) row (block
+(1, bn), lane-major like the tile's columns).  Mosaic refuses 1-D blocks
+smaller than XLA's 1-D tiling of the whole array, which is 1024 elements
+once the array has that many.
+
+The dots use ``Precision.HIGHEST``: the default radius puts the threshold
+at 1 - cos ~ 5e-7, below what a single bf16 MXU pass resolves.
 """
 from __future__ import annotations
 
@@ -33,17 +43,52 @@ __all__ = [
 COORD_PAD = 8  # zero-padded coordinate dimension (MXU K alignment)
 _NEG = -2.0  # dots lie in [-1, 1]
 _BIG = 2**30
+_HIGHEST = jax.lax.Precision.HIGHEST
 PAD_SEG = float(2**20)  # segment id assigned to padded rows (sorts last,
 #                         exactly representable in f32, matches no real seg)
 
 
+def _probe_row_spec(bm):
+    """Block of an (M, 1) per-probe column."""
+    return pl.BlockSpec((bm, 1), lambda i, j: (i, 0))
+
+
+def _bucket_row_spec(bn):
+    """Block of a (1, N) per-bucket-row vector."""
+    return pl.BlockSpec((1, bn), lambda i, j: (0, j))
+
+
+def _row_out_shape(m):
+    """(best_idx, best_dot, n_cand) as (M, 1) columns; best_idx indexes
+    the (concatenated) bucket rows."""
+    return [
+        jax.ShapeDtypeStruct((m, 1), jnp.int32),
+        jax.ShapeDtypeStruct((m, 1), jnp.float32),
+        jax.ShapeDtypeStruct((m, 1), jnp.int32),
+    ]
+
+
+def _dots(probe_ref, bucket_ref):
+    """(bm, bn) f32 dots of one probe tile with one bucket tile."""
+    return jax.lax.dot_general(
+        probe_ref[...],
+        bucket_ref[...],
+        (((1,), (1,)), ((), ())),
+        precision=_HIGHEST,
+        preferred_element_type=jnp.float32,
+    )
+
+
 def _accumulate(dots, j, bn, cos_thr, idx_ref, dot_ref, cnt_ref):
-    """Fold one (bm, bn) tile of dots into the running max/argmin-id/count."""
+    """Fold one (bm, bn) tile of dots into the running max/argmin-id/count
+    held in the (bm, 1) output blocks."""
     ids = jax.lax.broadcasted_iota(jnp.int32, dots.shape, 1) + j * bn
-    tile_best = jnp.max(dots, axis=1)
-    is_best = dots >= tile_best[:, None]
-    tile_idx = jnp.min(jnp.where(is_best, ids, jnp.int32(_BIG)), axis=1)
-    tile_cnt = jnp.sum((dots >= cos_thr).astype(jnp.int32), axis=1)
+    tile_best = jnp.max(dots, axis=1, keepdims=True)
+    is_best = dots >= tile_best
+    tile_idx = jnp.min(
+        jnp.where(is_best, ids, jnp.int32(_BIG)), axis=1, keepdims=True
+    )
+    tile_cnt = jnp.sum((dots >= cos_thr).astype(jnp.int32), axis=1, keepdims=True)
 
     run_best = dot_ref[...]
     improved = tile_best > run_best
@@ -63,15 +108,9 @@ def _kernel(bucket_ref, probe_ref, idx_ref, dot_ref, cnt_ref, *, cos_thr, bn, ba
         cnt_ref[...] = jnp.zeros_like(cnt_ref)
 
     def _body():
-        p = probe_ref[...]  # (bm, COORD_PAD)
-        b = bucket_ref[...]  # (bn, COORD_PAD)
-        dots = jax.lax.dot_general(
-            p,
-            b,
-            (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # (bm, bn)
-        _accumulate(dots, j, bn, cos_thr, idx_ref, dot_ref, cnt_ref)
+        _accumulate(
+            _dots(probe_ref, bucket_ref), j, bn, cos_thr, idx_ref, dot_ref, cnt_ref
+        )
 
     if band is None:
         _body()
@@ -93,7 +132,8 @@ def crossmatch_pallas(
     bm: int = 128,
     bn: int = 512,
     band: int | None = None,
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ):
     m, kp = probes.shape
     n, kb = bucket.shape
@@ -108,16 +148,8 @@ def crossmatch_pallas(
             pl.BlockSpec((bn, COORD_PAD), lambda i, j: (j, 0)),
             pl.BlockSpec((bm, COORD_PAD), lambda i, j: (i, 0)),
         ],
-        out_specs=[
-            pl.BlockSpec((bm,), lambda i, j: (i,)),
-            pl.BlockSpec((bm,), lambda i, j: (i,)),
-            pl.BlockSpec((bm,), lambda i, j: (i,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((m,), jnp.int32),  # best_idx
-            jax.ShapeDtypeStruct((m,), jnp.float32),  # best_dot
-            jax.ShapeDtypeStruct((m,), jnp.int32),  # n_cand
-        ],
+        out_specs=[_probe_row_spec(bm)] * 3,
+        out_shape=_row_out_shape(m),
         interpret=interpret,
     )(bucket, probes)
     return out
@@ -145,17 +177,11 @@ def _fused_kernel(
         idx_ref[...] = jnp.zeros_like(idx_ref)
         cnt_ref[...] = jnp.zeros_like(cnt_ref)
 
-    ps = pseg_ref[...]  # (bm,) f32 segment ids, ascending
-    bs = bseg_ref[...]  # (bn,) f32 segment ids, ascending
+    ps = pseg_ref[...]  # (bm, 1) f32 segment ids, ascending
+    bs = bseg_ref[...]  # (1, bn) f32 segment ids, ascending
 
     def _body():
-        p = probe_ref[...]
-        b = bucket_ref[...]
-        dots = jax.lax.dot_general(
-            p, b, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )  # (bm, bn)
-        same = ps[:, None] == bs[None, :]
-        dots = jnp.where(same, dots, jnp.float32(_NEG))
+        dots = jnp.where(ps == bs, _dots(probe_ref, bucket_ref), jnp.float32(_NEG))
         _accumulate(dots, j, bn, cos_thr, idx_ref, dot_ref, cnt_ref)
 
     overlap = (jnp.min(bs) <= jnp.max(ps)) & (jnp.max(bs) >= jnp.min(ps))
@@ -184,19 +210,13 @@ def _shared_kernel(
         idx_ref[...] = jnp.zeros_like(idx_ref)
         cnt_ref[...] = jnp.zeros_like(cnt_ref)
 
-    ps = pseg_ref[...]  # (bm,) f32 segment ids, ascending
-    bs = bseg_ref[...]  # (bn,) f32 segment ids, ascending
+    ps = pseg_ref[...]  # (bm, 1) f32 segment ids, ascending
+    bs = bseg_ref[...]  # (1, bn) f32 segment ids, ascending
 
     def _body():
-        p = probe_ref[...]
-        b = bucket_ref[...]
-        dots = jax.lax.dot_general(
-            p, b, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )  # (bm, bn)
-        same = ps[:, None] == bs[None, :]
-        dots = jnp.where(same, dots, jnp.float32(_NEG))
-        # Per-row thresholds broadcast against the (bm, bn) dots tile.
-        _accumulate(dots, j, bn, thr_ref[...][:, None], idx_ref, dot_ref, cnt_ref)
+        dots = jnp.where(ps == bs, _dots(probe_ref, bucket_ref), jnp.float32(_NEG))
+        # The (bm, 1) per-row thresholds broadcast against the dots tile.
+        _accumulate(dots, j, bn, thr_ref[...], idx_ref, dot_ref, cnt_ref)
 
     overlap = (jnp.min(bs) <= jnp.max(ps)) & (jnp.max(bs) >= jnp.min(ps))
     pl.when(overlap)(_body)
@@ -206,12 +226,13 @@ def _shared_kernel(
 def crossmatch_shared_pallas(
     bucket: jnp.ndarray,  # (N, COORD_PAD) f32, N % bn == 0, seg-sorted
     probes: jnp.ndarray,  # (M, COORD_PAD) f32, M % bm == 0, seg-sorted
-    bucket_seg: jnp.ndarray,  # (N,) f32 segment id per bucket row
-    probe_seg: jnp.ndarray,  # (M,) f32 segment id per probe row
-    probe_thr: jnp.ndarray,  # (M,) f32 per-probe cos threshold (traced!)
+    bucket_seg: jnp.ndarray,  # (1, N) f32 segment id per bucket row
+    probe_seg: jnp.ndarray,  # (M, 1) f32 segment id per probe row
+    probe_thr: jnp.ndarray,  # (M, 1) f32 per-probe cos threshold (traced!)
     bm: int = 128,
     bn: int = 512,
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ):
     m, kp = probes.shape
     n, kb = bucket.shape
@@ -225,20 +246,12 @@ def crossmatch_shared_pallas(
         in_specs=[
             pl.BlockSpec((bn, COORD_PAD), lambda i, j: (j, 0)),
             pl.BlockSpec((bm, COORD_PAD), lambda i, j: (i, 0)),
-            pl.BlockSpec((bn,), lambda i, j: (j,)),
-            pl.BlockSpec((bm,), lambda i, j: (i,)),
-            pl.BlockSpec((bm,), lambda i, j: (i,)),
+            _bucket_row_spec(bn),
+            _probe_row_spec(bm),
+            _probe_row_spec(bm),
         ],
-        out_specs=[
-            pl.BlockSpec((bm,), lambda i, j: (i,)),
-            pl.BlockSpec((bm,), lambda i, j: (i,)),
-            pl.BlockSpec((bm,), lambda i, j: (i,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((m,), jnp.int32),  # best_idx (concat rows)
-            jax.ShapeDtypeStruct((m,), jnp.float32),  # best_dot
-            jax.ShapeDtypeStruct((m,), jnp.int32),  # n_cand
-        ],
+        out_specs=[_probe_row_spec(bm)] * 3,
+        out_shape=_row_out_shape(m),
         interpret=interpret,
     )(bucket, probes, bucket_seg, probe_seg, probe_thr)
     return out
@@ -248,12 +261,13 @@ def crossmatch_shared_pallas(
 def crossmatch_fused_pallas(
     bucket: jnp.ndarray,  # (N, COORD_PAD) f32, N % bn == 0, seg-sorted
     probes: jnp.ndarray,  # (M, COORD_PAD) f32, M % bm == 0, seg-sorted
-    bucket_seg: jnp.ndarray,  # (N,) f32 segment id per bucket row
-    probe_seg: jnp.ndarray,  # (M,) f32 segment id per probe row
+    bucket_seg: jnp.ndarray,  # (1, N) f32 segment id per bucket row
+    probe_seg: jnp.ndarray,  # (M, 1) f32 segment id per probe row
     cos_thr: float,
     bm: int = 128,
     bn: int = 512,
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ):
     m, kp = probes.shape
     n, kb = bucket.shape
@@ -267,19 +281,11 @@ def crossmatch_fused_pallas(
         in_specs=[
             pl.BlockSpec((bn, COORD_PAD), lambda i, j: (j, 0)),
             pl.BlockSpec((bm, COORD_PAD), lambda i, j: (i, 0)),
-            pl.BlockSpec((bn,), lambda i, j: (j,)),
-            pl.BlockSpec((bm,), lambda i, j: (i,)),
+            _bucket_row_spec(bn),
+            _probe_row_spec(bm),
         ],
-        out_specs=[
-            pl.BlockSpec((bm,), lambda i, j: (i,)),
-            pl.BlockSpec((bm,), lambda i, j: (i,)),
-            pl.BlockSpec((bm,), lambda i, j: (i,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((m,), jnp.int32),  # best_idx (concat rows)
-            jax.ShapeDtypeStruct((m,), jnp.float32),  # best_dot
-            jax.ShapeDtypeStruct((m,), jnp.int32),  # n_cand
-        ],
+        out_specs=[_probe_row_spec(bm)] * 3,
+        out_shape=_row_out_shape(m),
         interpret=interpret,
     )(bucket, probes, bucket_seg, probe_seg)
     return out

@@ -23,6 +23,10 @@ argmax nor inflate ``n_cand`` — including when ``cos_thr <= 0`` (match
 radius >= pi/2), which used to count every zero-padded row.  The fused
 path gets the same guarantee from its segment mask (padded rows carry
 segment ``PAD_SEG``, which matches no real segment).
+
+Pallas runs compiled on a TPU and in interpret mode on the CPU.  The
+wrappers resolve ``interpret`` from the backend, outside ``jit``, when the
+caller leaves it ``None``; any other backend has no Pallas path.
 """
 from __future__ import annotations
 
@@ -54,12 +58,42 @@ def _pow2_ceil(n: int, floor: int = _MIN_SHAPE) -> int:
     return 1 << (n - 1).bit_length()
 
 
-def _pad_rows(x: jnp.ndarray, mult: int) -> jnp.ndarray:
+def _pad_rows(x: jnp.ndarray, mult: int, fill: float = 0.0) -> jnp.ndarray:
     n = x.shape[0]
     pad = (-n) % mult
     if pad:
-        x = jnp.pad(x, ((0, pad), (0, 0)))
+        x = jnp.pad(x, ((0, pad), (0, 0)), constant_values=fill)
     return x
+
+
+def _resolve_interpret(interpret: bool | None, use_pallas: bool) -> bool:
+    """The ``interpret`` flag a core is traced with: as given, or from the
+    backend when ``None`` (compiled on TPU, interpreted on CPU).  The jnp
+    path ignores it, so it is pinned there to keep one compile entry."""
+    if not use_pallas:
+        return False
+    if interpret is not None:
+        return bool(interpret)
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"the Pallas cross-match kernels run on tpu (compiled) or cpu "
+        f"(interpreted), not on {backend!r}; use use_pallas=False"
+    )
+
+
+def _segmented_operands(bucket8, probes8, bucket_seg, probe_seg, bm, bn):
+    """Block-pad both operands for the segmented kernels.  Padded rows get
+    segment ``PAD_SEG``; segment ids become a (1, N) row and an (M, 1)
+    column, the kernels' per-row layouts."""
+    bucket_p = _pad_rows(bucket8, bn)
+    probes_p = _pad_rows(probes8, bm)
+    bseg = _pad_rows(bucket_seg[:, None], bn, PAD_SEG).reshape(1, -1)
+    pseg = _pad_rows(probe_seg[:, None], bm, PAD_SEG)
+    return bucket_p, probes_p, bseg, pseg
 
 
 def _mark_probes(probes8: jnp.ndarray) -> jnp.ndarray:
@@ -111,7 +145,7 @@ def _crossmatch_jit(bucket8, probes8, cos_thr, use_pallas, bm, bn, band, interpr
     idx, dot, cnt = crossmatch_pallas(
         bucket_p, probes_p, cos_thr, bm=bm, bn=bn, band=band, interpret=interpret
     )
-    return idx[:m], dot[:m], cnt[:m]
+    return idx[:m, 0], dot[:m, 0], cnt[:m, 0]
 
 
 def crossmatch(
@@ -122,19 +156,21 @@ def crossmatch(
     bm: int = 128,
     bn: int = 512,
     band: int | None = None,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ):
     """Cross-match ``probes`` against ``bucket`` (both (?,3) unit vectors).
 
     Returns (best_idx, best_dot, n_cand), each of length len(probes).
-    ``use_pallas=False`` uses the jnp reference path (fast on CPU);
-    ``use_pallas=True`` runs the TPU kernel (interpret mode off-TPU).
+    ``use_pallas=False`` uses the jnp reference path;
+    ``use_pallas=True`` runs the Pallas kernel, compiled on a TPU and
+    interpreted on the CPU unless ``interpret`` says otherwise.
 
     Both operands are padded to the next power of two (in host numpy)
     before entering the jitted core, so the number of distinct compiled
     shapes over a whole trace is O(log2(max probe count)) rather than
     O(#batches).
     """
+    interpret = _resolve_interpret(interpret, use_pallas)
     bucket8, probes8, n_true, m_true = _host_prepare(bucket, probes)
     idx, dot, cnt = _crossmatch_jit(
         bucket8, probes8, float(cos_thr), use_pallas, bm, bn, band, interpret
@@ -147,14 +183,11 @@ def crossmatch(
 def jit_cache_size() -> int:
     """Total shapes compiled across the single-bucket, fused, and
     shared-plan cores (benchmarks gate this staying O(log max batch))."""
-    try:
-        return int(
-            _crossmatch_jit._cache_size()
-            + _crossmatch_fused_jit._cache_size()
-            + _crossmatch_shared_jit._cache_size()
-        )
-    except AttributeError:  # very old jax
-        return -1
+    return int(
+        _crossmatch_jit._cache_size()
+        + _crossmatch_fused_jit._cache_size()
+        + _crossmatch_shared_jit._cache_size()
+    )
 
 
 @functools.partial(
@@ -166,24 +199,14 @@ def _crossmatch_fused_jit(
     m = probes8.shape[0]
     if not use_pallas:
         return crossmatch_fused_ref(bucket8, probes8, bucket_seg, probe_seg, cos_thr)
-    n_in = bucket8.shape[0]
-    bucket_p = _pad_rows(bucket8, bn)
-    probes_p = _pad_rows(probes8, bm)
-    pad_b = bucket_p.shape[0] - n_in
-    if pad_b:
-        bucket_seg = jnp.concatenate(
-            [bucket_seg, jnp.full((pad_b,), PAD_SEG, jnp.float32)]
-        )
-    pad_p = probes_p.shape[0] - m
-    if pad_p:
-        probe_seg = jnp.concatenate(
-            [probe_seg, jnp.full((pad_p,), PAD_SEG, jnp.float32)]
-        )
+    bucket_p, probes_p, bseg, pseg = _segmented_operands(
+        bucket8, probes8, bucket_seg, probe_seg, bm, bn
+    )
     idx, dot, cnt = crossmatch_fused_pallas(
-        bucket_p, probes_p, bucket_seg, probe_seg, cos_thr,
+        bucket_p, probes_p, bseg, pseg, cos_thr,
         bm=bm, bn=bn, interpret=interpret,
     )
-    return idx[:m], dot[:m], cnt[:m]
+    return idx[:m, 0], dot[:m, 0], cnt[:m, 0]
 
 
 @functools.partial(jax.jit, static_argnames=("use_pallas", "bm", "bn", "interpret"))
@@ -195,27 +218,15 @@ def _crossmatch_shared_jit(
         return crossmatch_shared_ref(
             bucket8, probes8, bucket_seg, probe_seg, probe_thr
         )
-    n_in = bucket8.shape[0]
-    bucket_p = _pad_rows(bucket8, bn)
-    probes_p = _pad_rows(probes8, bm)
-    pad_b = bucket_p.shape[0] - n_in
-    if pad_b:
-        bucket_seg = jnp.concatenate(
-            [bucket_seg, jnp.full((pad_b,), PAD_SEG, jnp.float32)]
-        )
-    pad_p = probes_p.shape[0] - m
-    if pad_p:
-        probe_seg = jnp.concatenate(
-            [probe_seg, jnp.full((pad_p,), PAD_SEG, jnp.float32)]
-        )
-        probe_thr = jnp.concatenate(
-            [probe_thr, jnp.full((pad_p,), _PAD_THR, jnp.float32)]
-        )
+    bucket_p, probes_p, bseg, pseg = _segmented_operands(
+        bucket8, probes8, bucket_seg, probe_seg, bm, bn
+    )
+    thr = _pad_rows(probe_thr[:, None], bm, _PAD_THR)
     idx, dot, cnt = crossmatch_shared_pallas(
-        bucket_p, probes_p, bucket_seg, probe_seg, probe_thr,
+        bucket_p, probes_p, bseg, pseg, thr,
         bm=bm, bn=bn, interpret=interpret,
     )
-    return idx[:m], dot[:m], cnt[:m]
+    return idx[:m, 0], dot[:m, 0], cnt[:m, 0]
 
 
 def crossmatch_shared(
@@ -227,7 +238,7 @@ def crossmatch_shared(
     use_pallas: bool = False,
     bm: int = 128,
     bn: int = 512,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ):
     """Shared-plan cross-match: the query axis fused into ONE device call.
 
@@ -242,6 +253,7 @@ def crossmatch_shared(
     Returns (best_idx, best_dot, n_cand) of length len(probes); best_idx
     indexes the concatenated bucket array.
     """
+    interpret = _resolve_interpret(interpret, use_pallas)
     bucket8, probes8, n_true, m_true = _host_prepare(bucket, probes)
     # Segment mask fences padded/real rows, exactly as in the fused path.
     bucket8[:, _MARKER_COL] = 0.0
@@ -269,7 +281,7 @@ def crossmatch_fused(
     use_pallas: bool = False,
     bm: int = 128,
     bn: int = 512,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ):
     """Fused multi-bucket cross-match: ONE device call for k buckets.
 
@@ -283,6 +295,7 @@ def crossmatch_fused(
     Shapes are padded to powers of two (padded rows get segment
     ``PAD_SEG``), bounding compile count over a trace.
     """
+    interpret = _resolve_interpret(interpret, use_pallas)
     bucket8, probes8, n_true, m_true = _host_prepare(bucket, probes)
     # The segment mask replaces the marker column: padded/real row fencing
     # comes from PAD_SEG, so neutralize the marker values set above.

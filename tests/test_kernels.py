@@ -26,7 +26,8 @@ class TestCrossmatch:
         bkt, prb = _unit(n, 1), _unit(m, 2)
         thr = float(np.cos(radius))
         ri, rd, rc = cm_ops.crossmatch(bkt, prb, thr, use_pallas=False)
-        pi, pd, pc = cm_ops.crossmatch(bkt, prb, thr, use_pallas=True, bm=128, bn=256)
+        pi, pd, pc = cm_ops.crossmatch(bkt, prb, thr, use_pallas=True, bm=128, bn=256,
+                                     interpret=True)
         np.testing.assert_array_equal(np.asarray(rc), np.asarray(pc))
         np.testing.assert_allclose(np.asarray(rd), np.asarray(pd), rtol=1e-6)
         # argmax may tie; verify the dot of the chosen index is the max
@@ -40,14 +41,17 @@ class TestCrossmatch:
         bkt, prb = _unit(500, 3), _unit(200, 4)
         thr = float(np.cos(0.05))
         ri, rd, rc = cm_ops.crossmatch(bkt, prb, thr, use_pallas=False)
-        pi, pd, pc = cm_ops.crossmatch(bkt, prb, thr, use_pallas=True, bm=bm, bn=bn)
+        pi, pd, pc = cm_ops.crossmatch(bkt, prb, thr, use_pallas=True, bm=bm, bn=bn,
+                                     interpret=True)
         np.testing.assert_array_equal(np.asarray(rc), np.asarray(pc))
         np.testing.assert_allclose(np.asarray(rd), np.asarray(pd), rtol=1e-6)
 
     def test_self_match(self):
         """Every point matches itself at any positive radius."""
         pts = _unit(300, 5)
-        _, d, c = cm_ops.crossmatch(pts, pts, float(np.cos(0.01)), use_pallas=True)
+        _, d, c = cm_ops.crossmatch(
+            pts, pts, float(np.cos(0.01)), use_pallas=True, interpret=True
+        )
         assert (np.asarray(c) >= 1).all()
         np.testing.assert_allclose(np.asarray(d), 1.0, atol=1e-5)
 
@@ -59,9 +63,11 @@ class TestCrossmatch:
         order = np.argsort(htm_id(pts, level=8), kind="stable")
         pts = pts[order]
         thr = float(np.cos(0.01))
-        fi, fd, fc = cm_ops.crossmatch(pts, pts, thr, use_pallas=True, bm=128, bn=128)
+        fi, fd, fc = cm_ops.crossmatch(
+            pts, pts, thr, use_pallas=True, bm=128, bn=128, interpret=True
+        )
         bi, bd, bc = cm_ops.crossmatch(
-            pts, pts, thr, use_pallas=True, bm=128, bn=128, band=0
+            pts, pts, thr, use_pallas=True, bm=128, bn=128, band=0, interpret=True
         )
         # band=0 keeps only the diagonal tile: self-match must survive
         np.testing.assert_allclose(np.asarray(bd), 1.0, atol=1e-5)
@@ -74,7 +80,7 @@ class TestCrossmatch:
         bkt, prb = _unit(n, n), _unit(m, m + 1)
         thr = float(np.cos(0.05))
         ri, rd, rc = cm_ops.crossmatch(bkt, prb, thr, use_pallas=False)
-        pi, pd, pc = cm_ops.crossmatch(bkt, prb, thr, use_pallas=True)
+        pi, pd, pc = cm_ops.crossmatch(bkt, prb, thr, use_pallas=True, interpret=True)
         np.testing.assert_array_equal(np.asarray(rc), np.asarray(pc))
 
     @pytest.mark.parametrize("radius", [1.7, 2.0, 3.0])
@@ -87,7 +93,9 @@ class TestCrossmatch:
         thr = float(np.cos(radius))
         assert thr <= 0.0
         ri, rd, rc = crossmatch_ref(jnp.asarray(bkt), jnp.asarray(prb), thr)
-        _, d, c = cm_ops.crossmatch(bkt, prb, thr, use_pallas=use_pallas, bm=128, bn=256)
+        _, d, c = cm_ops.crossmatch(
+            bkt, prb, thr, use_pallas=use_pallas, bm=128, bn=256, interpret=True
+        )
         np.testing.assert_array_equal(np.asarray(c), np.asarray(rc))
         np.testing.assert_allclose(np.asarray(d), np.asarray(rd), rtol=1e-6)
 
@@ -119,7 +127,8 @@ class TestCrossmatchFused:
         bkts, prbs, B, P, bseg, pseg = self._segments(sizes_b, sizes_p)
         thr = float(np.cos(radius))
         fi, fd, fc = cm_ops.crossmatch_fused(
-            B, P, bseg, pseg, thr, use_pallas=use_pallas, bm=128, bn=128
+            B, P, bseg, pseg, thr, use_pallas=use_pallas, bm=128, bn=128,
+            interpret=True,
         )
         fi, fd, fc = map(np.asarray, (fi, fd, fc))
         off_b = np.cumsum([0] + sizes_b)
@@ -145,7 +154,7 @@ class TestCrossmatchFused:
         pseg = np.full(10, 3, np.int32)  # segment 3 has no bucket rows
         _, d, c = cm_ops.crossmatch_fused(
             B, P, bseg, pseg, float(np.cos(3.0)), use_pallas=use_pallas,
-            bm=128, bn=128,
+            bm=128, bn=128, interpret=True,
         )
         assert (np.asarray(c) == 0).all()
         assert (np.asarray(d) <= -1.5).all()  # masked sentinel, never a match

@@ -3,9 +3,9 @@
 Three layers under test:
 
 * kernel — ``crossmatch_shared`` (traced per-probe thresholds) must be
-  bit-identical to the per-query ``crossmatch`` loop on both the jnp
-  reference path and the Pallas tile-skip path, across padded/sentinel
-  edge shapes (property-based);
+  bit-identical to the per-query ``crossmatch`` loop on the Pallas
+  tile-skip path, and agree with it to the ulp bound below on the jnp
+  reference path, across padded/sentinel edge shapes (property-based);
 * compile bounding — K distinct predicates in one shared call must cost
   at most one ``jit_cache_size`` entry per pow2 shape pair, not K;
 * control + engine — the AIMD ``share_width`` law, and the cross-match
@@ -57,6 +57,55 @@ def _bits(a):
     return np.ascontiguousarray(np.asarray(a, np.float32)).view(np.int32)
 
 
+# XLA's CPU dot picks its kernel by operand shape: a (3, 8) x (8, 8) and a
+# (3, 8) x (8, 64) dot of the same rows already round the sum of the three
+# coordinate products differently.  The marker column adds exact zeros and
+# plays no part.  So whenever two calls pad the bucket operand to different
+# lengths (the shared call holds the whole concatenation, a per-query call
+# one bucket; the jnp path is one dot, the Pallas path bn-wide tiles), one
+# (probe, object) pair may come out an ulp or two apart.  Those calls are
+# held to best_dot within _DOT_ULPS ulps, and to equal n_cand / best_idx
+# wherever a shift of that size cannot move a pair across its threshold or
+# swap the top two: _BAND is twice that shift, for dots in [0.5, 1) where
+# the float32 ulp is 2**-24.  Calls with equal tiles stay bit-identical.
+_DOT_ULPS = 2
+_BAND = 2 * _DOT_ULPS * 2.0**-24
+
+
+def _ulp_sensitive(probes, bucket, thr):
+    """Probes whose n_cand or best_idx a _DOT_ULPS-sized shift of a dot
+    can change, from an f64 join of the same float32 inputs."""
+    d = (
+        np.asarray(probes, np.float32).astype(np.float64)
+        @ np.asarray(bucket, np.float32).astype(np.float64).T
+    )
+    thr = np.asarray(thr, np.float32).astype(np.float64).reshape(-1, 1)
+    near_thr = (np.abs(d - thr) <= _BAND).any(axis=1)
+    top2 = np.sort(d, axis=1)[:, -2:]
+    tie = (top2[:, -1] - top2[:, 0] <= _BAND) & (d.shape[1] > 1)
+    return near_thr | tie
+
+
+def _assert_same_join(got, want, sensitive=None):
+    """(best_idx, best_dot, n_cand) equal bit for bit, or, given the
+    ulp-sensitive probes, to the bound above."""
+    (g_idx, g_dot, g_cnt), (w_idx, w_dot, w_cnt) = (
+        map(np.asarray, got), map(np.asarray, want)
+    )
+    if sensitive is None:
+        np.testing.assert_array_equal(g_idx, w_idx)
+        np.testing.assert_array_equal(_bits(g_dot), _bits(w_dot))
+        np.testing.assert_array_equal(g_cnt, w_cnt)
+        return
+    np.testing.assert_array_max_ulp(
+        np.asarray(g_dot, np.float32), np.asarray(w_dot, np.float32),
+        maxulp=_DOT_ULPS,
+    )
+    firm = ~sensitive
+    np.testing.assert_array_equal(g_idx[firm], w_idx[firm])
+    np.testing.assert_array_equal(g_cnt[firm], w_cnt[firm])
+
+
 class TestSharedKernel:
     @given(st.integers(1, 4), st.integers(1, 6), st.integers(1, 20),
            st.integers(0, 2))
@@ -64,8 +113,10 @@ class TestSharedKernel:
     def test_shared_equals_per_query_loop(
         self, n_buckets, n_queries, rows_hi, n_empty
     ):
-        """One shared masked call == the per-query crossmatch loop, bit
-        for bit, on both kernel paths and across edge shapes."""
+        """One shared masked call == the per-query crossmatch loop across
+        edge shapes: bit for bit on the Pallas path (both calls see the
+        same 8x8 tiles), to the ulp bound on the jnp path (one dot each,
+        of different padded lengths)."""
         seed = 100_000 * n_buckets + 10_000 * n_queries + 13 * rows_hi + n_empty
         rng = np.random.default_rng(seed)
         bucket_cat, bseg, row_off, payloads, queries = _make_case(
@@ -85,15 +136,14 @@ class TestSharedKernel:
             ))
             at = 0
             for b, probes, thr in queries:
-                idx, dot, cnt = cm_ops.crossmatch(
-                    payloads[b], probes, thr, **kw
-                )
+                want = cm_ops.crossmatch(payloads[b], probes, thr, **kw)
                 sl = slice(at, at + len(probes))
-                np.testing.assert_array_equal(
-                    s_idx[sl] - row_off[b], np.asarray(idx)
+                got = (s_idx[sl] - row_off[b], s_dot[sl], s_cnt[sl])
+                sensitive = (
+                    None if use_pallas
+                    else _ulp_sensitive(probes, payloads[b], thr)
                 )
-                np.testing.assert_array_equal(_bits(s_dot[sl]), _bits(dot))
-                np.testing.assert_array_equal(s_cnt[sl], np.asarray(cnt))
+                _assert_same_join(got, want, sensitive)
                 at += len(probes)
 
     def test_single_query_single_probe(self):
@@ -107,6 +157,9 @@ class TestSharedKernel:
         assert float(dot[0]) == pytest.approx(1.0)
 
     def test_ref_vs_pallas_bit_identical(self):
+        """The jnp and Pallas shared paths agree to the ulp bound (one dot
+        against 8x8 tiles), and bit for bit at tiles as wide as the padded
+        operands, where both paths run one dot of the same shape."""
         rng = np.random.default_rng(7)
         bucket_cat, bseg, row_off, payloads, queries = _make_case(
             rng, 3, 4, 12, 1
@@ -125,9 +178,16 @@ class TestSharedKernel:
             bucket_cat, probes_cat, bseg, pseg, thr_row,
             use_pallas=True, bm=8, bn=8, interpret=True,
         )
-        np.testing.assert_array_equal(np.asarray(r[0]), np.asarray(p[0]))
-        np.testing.assert_array_equal(_bits(r[1]), _bits(p[1]))
-        np.testing.assert_array_equal(np.asarray(r[2]), np.asarray(p[2]))
+        sensitive = np.concatenate(
+            [_ulp_sensitive(pr, payloads[b], t) for b, pr, t in queries]
+        )
+        _assert_same_join(p, r, sensitive)
+        whole = cm_ops.crossmatch_shared(
+            bucket_cat, probes_cat, bseg, pseg, thr_row, use_pallas=True,
+            bm=cm_ops._pow2_ceil(len(probes_cat)),
+            bn=cm_ops._pow2_ceil(len(bucket_cat)), interpret=True,
+        )
+        _assert_same_join(whole, r)
 
     def test_shared_compiles_once_for_k_predicates(self):
         """K distinct thresholds at one pow2 shape pair: exactly one new
