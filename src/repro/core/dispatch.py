@@ -19,6 +19,12 @@ consulted by one benchmark.  ``DispatchLoop`` owns that round now:
           the ControlLoop sizes it) and issue the next stages
       6. cost = stall + execute(decisions, vector)  # engine-specific compute
       7. advance the clock, run completion, count batches/dispatches
+      8. the round taps (journal, decision log, metrics)
+
+With ``phases`` set (obs on, wall clock), the round is also one span
+whose children are the measured phases: ``select`` (steps 1-5), the
+executor's own (fetch, gather, launch, readback, route) and ``complete``
+(steps 7-8).
 
 Engines supply only ``execute`` (the device call + result routing) and
 optionally ``complete`` (defaults to ``wm.complete_bucket`` per decision).
@@ -108,6 +114,10 @@ class DispatchLoop:
         self.last_vector: Optional[ControlVector] = None
         self.last_tenant_vectors: Optional[dict[str, ControlVector]] = None
         self.on_round = on_round  # decision-log tap (tests/replay.py)
+        # Wall-clock phase spans (repro.obs.phases.PhaseSpans), set by
+        # Observability.attach_loop(clock="wall"); None keeps the round
+        # to one ``is None`` test per phase.
+        self.phases = None
         self._occupancy = 0.0  # last round's batch fill fraction
         self._occ_by_tenant: dict[str, float] = {}
         self._shared_occ = 0.0  # last shared-plan round's query fill
@@ -252,6 +262,10 @@ class DispatchLoop:
 
     # -- one scheduling round ----------------------------------------------------
     def round(self) -> Optional[DispatchOutcome]:
+        ph = self.phases
+        if ph is not None:
+            ph.begin_round(self.dispatches)
+            ph.phase("select")
         tenant_vectors: Optional[dict[str, ControlVector]] = None
         if self._plane is not None:
             vector, spill_changed, tenant_vectors = self._consult_plane()
@@ -288,6 +302,8 @@ class DispatchLoop:
         else:
             d = self.scheduler.select(self.wm, self.cache, self.clock)
             decisions = [] if d is None else [d]
+        if ph is not None:
+            ph.selected(decisions)
         if not decisions:
             return None
 
@@ -303,6 +319,8 @@ class DispatchLoop:
                 horizon=vector.horizon or None,
             )
         cost = stall + self._execute(decisions, vector)
+        if ph is not None:
+            ph.phase("complete")
         self.clock += cost
         self.busy += cost
         if self.prefetch is not None:
@@ -332,6 +350,8 @@ class DispatchLoop:
         )
         if self.on_round is not None:
             self.on_round(outcome)
+        if ph is not None:
+            ph.end_round()
         return outcome
 
     # -- multi-tenant consult -----------------------------------------------------

@@ -117,12 +117,16 @@ class CrossMatchEngine:
         self.obs = None
         if obs:
             # Lazy import (off-path never touches repro.obs).  Crossmatch
-            # executes real device/array work, so spans ride on
-            # perf_counter marks; decisions still come off the tap only.
+            # executes real device/array work, so its rounds are timed on
+            # perf_counter by phase spans (loop.phases); decisions still
+            # come off the tap only.
+            from ..kernels.crossmatch import ops as cm_ops
             from ..obs import ensure as _obs_ensure
 
             self.obs = _obs_ensure(obs)
-            self.obs.attach_loop(self.loop, track=0, clock="wall")
+            self.obs.attach_loop(
+                self.loop, track=0, clock="wall", h2d_bytes=cm_ops.h2d_bytes
+            )
 
     # -- loop-owned counters (kept as attributes for back-compat) --------------
     @property
@@ -199,6 +203,9 @@ class CrossMatchEngine:
         happens (the decision's snapshot only fed the priority score)."""
         b = decision.bucket_id
         in_cache = self.cache.contains(b)
+        ph = self.loop.phases
+        if ph is not None:
+            ph.phase("fetch", bucket=b, hit=int(in_cache))
         plan = (
             self.hybrid.plan(decision.queue_size, in_cache)
             if self.hybrid
@@ -222,6 +229,8 @@ class CrossMatchEngine:
                 decision.queue_size, in_cache, self.wm.spilled_fraction(b)
             )
         )
+        if ph is not None:
+            ph.end()
         return plan, payload, cost
 
     def _gather_probes(self, bucket_id: int):
@@ -287,7 +296,14 @@ class CrossMatchEngine:
         """DispatchLoop executor: route the round to the shared-plan path,
         the per-predicate-class path (heterogeneous predicates without a
         shared plan), or the historical batched/fused path.  Returns the
-        round's wall-clock cost."""
+        round's cost on the cost model's clock (T_b per bucket read, T_m
+        per probe), not its wall time.
+
+        With ``loop.phases`` set, every path records the same phase spans:
+        ``fetch`` per bucket, ``gather`` (probe rows, f32 casts, segment
+        and operand concatenation), ``launch`` (the ``ops.crossmatch*``
+        call: padding, transfer, dispatch and any compile), ``readback``
+        (``np.asarray`` of the outputs) and ``route``."""
         if self.shared_plan:
             return self.execute_shared(decisions, vector)
         if self._has_query_predicates:
@@ -299,24 +315,32 @@ class CrossMatchEngine:
         round (single bucket, or the fuse_k segment-masked fused call)."""
         from ..kernels.crossmatch import ops as cm_ops
 
+        ph = self.loop.phases
         total_cost = 0.0
         if len(decisions) == 1:
             decision = decisions[0]
             b = decision.bucket_id
             _, payload, cost = self._plan_and_fetch(decision)
             total_cost += cost
+            if ph is not None:
+                ph.phase("gather")
             units, probe_pos, owners, probe_local = self._gather_probes(b)
             self.max_probe_batch = max(self.max_probe_batch, len(probe_pos))
+            bucket32 = np.asarray(payload["positions"], dtype=np.float32)
+            probes32 = probe_pos.astype(np.float32)
+            if ph is not None:
+                ph.phase("launch")
             # --- the shared pass: one batched device call for every query ---
-            best_idx, best_dot, n_cand = cm_ops.crossmatch(
-                np.asarray(payload["positions"], dtype=np.float32),
-                probe_pos.astype(np.float32),
-                self.cos_thr,
-                use_pallas=self.use_pallas,
+            out = cm_ops.crossmatch(
+                bucket32, probes32, self.cos_thr, use_pallas=self.use_pallas
             )
+            if ph is not None:
+                ph.phase("readback")
+            best_idx, best_dot, n_cand = (np.asarray(a) for a in out)
+            if ph is not None:
+                ph.phase("route")
             self._route(
-                b, units, owners, probe_local,
-                np.asarray(best_idx), np.asarray(best_dot), np.asarray(n_cand),
+                b, units, owners, probe_local, best_idx, best_dot, n_cand,
                 payload,
             )
         else:
@@ -328,6 +352,8 @@ class CrossMatchEngine:
                 b = decision.bucket_id
                 _, payload, cost = self._plan_and_fetch(decision)
                 total_cost += cost
+                if ph is not None:
+                    ph.phase("gather")
                 units, probe_pos, owners, probe_local = self._gather_probes(b)
                 pos = np.asarray(payload["positions"], dtype=np.float32)
                 bucket_parts.append(pos)
@@ -342,17 +368,22 @@ class CrossMatchEngine:
             self.max_probe_batch = max(
                 self.max_probe_batch, sum(len(p) for p in probe_parts)
             )
-            best_idx, best_dot, n_cand = cm_ops.crossmatch_fused(
+            operands = (
                 np.concatenate(bucket_parts),
                 np.concatenate(probe_parts),
                 np.concatenate(bseg),
                 np.concatenate(pseg),
-                self.cos_thr,
-                use_pallas=self.use_pallas,
             )
-            best_idx = np.asarray(best_idx)
-            best_dot = np.asarray(best_dot)
-            n_cand = np.asarray(n_cand)
+            if ph is not None:
+                ph.phase("launch")
+            out = cm_ops.crossmatch_fused(
+                *operands, self.cos_thr, use_pallas=self.use_pallas
+            )
+            if ph is not None:
+                ph.phase("readback")
+            best_idx, best_dot, n_cand = (np.asarray(a) for a in out)
+            if ph is not None:
+                ph.phase("route")
             p_off = 0
             for b, payload, units, owners, probe_local, row_off, n_p in per_bucket:
                 sl = slice(p_off, p_off + n_p)
@@ -364,7 +395,8 @@ class CrossMatchEngine:
                     b, units, owners, probe_local,
                     local_idx, best_dot[sl], n_cand[sl], payload,
                 )
-
+        if ph is not None:
+            ph.end()
         return total_cost
 
     def _execute_per_predicate(self, decisions) -> float:
@@ -375,12 +407,15 @@ class CrossMatchEngine:
         stays a pure performance switch with bit-equal results."""
         from ..kernels.crossmatch import ops as cm_ops
 
+        ph = self.loop.phases
         total_cost = 0.0
         n_calls = 0
         for decision in decisions:
             b = decision.bucket_id
             _, payload, cost = self._plan_and_fetch(decision)
             total_cost += cost
+            if ph is not None:
+                ph.phase("gather")
             units, probe_pos, owners, probe_local = self._gather_probes(b)
             self.max_probe_batch = max(self.max_probe_batch, len(probe_pos))
             pos = np.asarray(payload["positions"], dtype=np.float32)
@@ -390,18 +425,29 @@ class CrossMatchEngine:
             best_dot = np.zeros(len(owners), np.float32)
             n_cand = np.zeros(len(owners), np.int64)
             for thr in np.unique(thr_row):
+                if ph is not None:
+                    ph.phase("gather")
                 sel = thr_row == thr
-                bi, bd, nc = cm_ops.crossmatch(
-                    pos, probes32[sel], float(thr), use_pallas=self.use_pallas
+                probes_sel = probes32[sel]
+                if ph is not None:
+                    ph.phase("launch")
+                out = cm_ops.crossmatch(
+                    pos, probes_sel, float(thr), use_pallas=self.use_pallas
                 )
-                best_idx[sel] = np.asarray(bi)
-                best_dot[sel] = np.asarray(bd)
-                n_cand[sel] = np.asarray(nc)
+                if ph is not None:
+                    ph.phase("readback")
+                best_idx[sel], best_dot[sel], n_cand[sel] = (
+                    np.asarray(a) for a in out
+                )
                 n_calls += 1
+            if ph is not None:
+                ph.phase("route")
             self._route(
                 b, units, owners, probe_local, best_idx, best_dot, n_cand,
                 payload, mag_cut_row=mag_row,
             )
+        if ph is not None:
+            ph.end()
         self.loop.note_device_dispatches(n_calls)
         return total_cost
 
@@ -433,6 +479,7 @@ class CrossMatchEngine:
             for d in bucket_group
         ]
         width = getattr(vector, "share_width", 0) or self.share_width
+        ph = self.loop.phases
         total_cost = 0.0
         n_calls = 0
 
@@ -467,6 +514,8 @@ class CrossMatchEngine:
             b = decision.bucket_id
             _, payload, cost = self._plan_and_fetch(decision)
             total_cost += cost
+            if ph is not None:
+                ph.phase("gather")
             units, probe_pos, owners, probe_local = self._gather_probes(b)
             pos = np.asarray(payload["positions"], dtype=np.float32)
             bucket_parts.append(pos)
@@ -497,26 +546,33 @@ class CrossMatchEngine:
         n_cand = np.zeros(len(owners_cat), np.int64)
         chunks = [qids[i : i + width] for i in range(0, len(qids), width)] or [[]]
         for chunk in chunks:
+            if ph is not None:
+                ph.phase("gather")
             rows = np.isin(owners_cat, chunk)
             if not rows.any():
                 continue
-            bi, bd, nc = cm_ops.crossmatch_shared(
-                bucket_cat,
-                probes_cat[rows],
-                bseg_cat,
-                pseg_cat[rows],
-                thr_row[rows],
+            probes_rows, pseg_rows, thr_rows = (
+                probes_cat[rows], pseg_cat[rows], thr_row[rows]
+            )
+            if ph is not None:
+                ph.phase("launch")
+            out = cm_ops.crossmatch_shared(
+                bucket_cat, probes_rows, bseg_cat, pseg_rows, thr_rows,
                 use_pallas=self.use_pallas,
             )
-            best_idx[rows] = np.asarray(bi)
-            best_dot[rows] = np.asarray(bd)
-            n_cand[rows] = np.asarray(nc)
+            if ph is not None:
+                ph.phase("readback")
+            best_idx[rows], best_dot[rows], n_cand[rows] = (
+                np.asarray(a) for a in out
+            )
             n_calls += 1
         occupancy = (
             len(qids) / (len(chunks) * width) if qids and chunks else 0.0
         )
         self.loop.note_device_dispatches(n_calls, shared_occupancy=occupancy)
 
+        if ph is not None:
+            ph.phase("route")
         p_off = 0
         for b, payload, units, owners, probe_local, row_off, n_p in per_bucket:
             sl = slice(p_off, p_off + n_p)
@@ -529,6 +585,8 @@ class CrossMatchEngine:
                 local_idx, best_dot[sl], n_cand[sl], payload,
                 mag_cut_row=mag_row[sl],
             )
+        if ph is not None:
+            ph.end()
         return total_cost
 
     # -- drive a whole trace -------------------------------------------------------

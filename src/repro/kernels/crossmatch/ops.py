@@ -7,7 +7,9 @@ Handles padding and dispatch for two entry points:
                         (*shape bucketing*), so a query trace triggers
                         O(log max_M) jit compilations instead of one per
                         distinct batch size; ``jit_cache_size()`` exposes
-                        the compile count for benchmarks.
+                        the compile count for benchmarks, ``h2d_bytes()``
+                        the bytes of the padded operands handed to the
+                        jitted cores.
 ``crossmatch_fused``  — k buckets in ONE device call: payloads and probe
                         batches are concatenated with segment ids and the
                         join is evaluated as a segment-masked matmul
@@ -45,12 +47,19 @@ from .kernel import (
 )
 from .ref import crossmatch_fused_ref, crossmatch_ref, crossmatch_shared_ref
 
-__all__ = ["crossmatch", "crossmatch_fused", "crossmatch_shared", "jit_cache_size"]
+__all__ = [
+    "crossmatch", "crossmatch_fused", "crossmatch_shared", "jit_cache_size",
+    "h2d_bytes",
+]
 
 _PAD_THR = 2.0  # threshold for padded probe rows: above any dot, passes never
 
 _MARKER_COL = 3  # first zero-padded coordinate column; see module docstring
 _MIN_SHAPE = 8  # floor for power-of-two shape buckets
+
+# Running total of the bytes of host-built operands passed to the jitted
+# cores: each is transferred to the device on every call.
+_h2d = [0]
 
 
 def _pow2_ceil(n: int, floor: int = _MIN_SHAPE) -> int:
@@ -172,12 +181,19 @@ def crossmatch(
     """
     interpret = _resolve_interpret(interpret, use_pallas)
     bucket8, probes8, n_true, m_true = _host_prepare(bucket, probes)
+    _h2d[0] += bucket8.nbytes + probes8.nbytes
     idx, dot, cnt = _crossmatch_jit(
         bucket8, probes8, float(cos_thr), use_pallas, bm, bn, band, interpret
     )
     # Padded rows cannot win (marker dot -2), but clamp for belt-and-braces.
     idx = jnp.minimum(idx[:m_true], max(n_true - 1, 0))
     return idx, dot[:m_true], cnt[:m_true]
+
+
+def h2d_bytes() -> int:
+    """Bytes of the padded operands handed to the jitted cores so far, in
+    this process (the host-to-device traffic of the cross-match calls)."""
+    return _h2d[0]
 
 
 def jit_cache_size() -> int:
@@ -264,6 +280,9 @@ def crossmatch_shared(
     pseg[:m_true] = np.asarray(probe_seg, np.float32)
     thr = np.full(probes8.shape[0], _PAD_THR, np.float32)
     thr[:m_true] = np.asarray(probe_thr, np.float32)
+    _h2d[0] += (
+        bucket8.nbytes + probes8.nbytes + bseg.nbytes + pseg.nbytes + thr.nbytes
+    )
     idx, dot, cnt = _crossmatch_shared_jit(
         bucket8, probes8, jnp.asarray(bseg), jnp.asarray(pseg), jnp.asarray(thr),
         use_pallas, bm, bn, interpret,
@@ -305,6 +324,7 @@ def crossmatch_fused(
     bseg[:n_true] = np.asarray(bucket_seg, np.float32)
     pseg = np.full(probes8.shape[0], PAD_SEG, np.float32)
     pseg[:m_true] = np.asarray(probe_seg, np.float32)
+    _h2d[0] += bucket8.nbytes + probes8.nbytes + bseg.nbytes + pseg.nbytes
     idx, dot, cnt = _crossmatch_fused_jit(
         bucket8, probes8, jnp.asarray(bseg), jnp.asarray(pseg),
         float(cos_thr), use_pallas, bm, bn, interpret,

@@ -16,6 +16,8 @@ Public surface:
   ``run_policy`` / ``LifeRaftEngine`` / ``ShardedServingEngine`` /
   ``CrossMatchEngine`` / ``ServiceDaemon``.
 * :class:`ObsConfig` — bounds and sampling knobs.
+* :class:`PhaseSpans` — wall-clock phase spans inside a served round and
+  a submit (histogram + ``jax.profiler.TraceAnnotation``).
 * :class:`MetricsRegistry` / :class:`RoundTracer` / :class:`ControlExplain`
   — the underlying stores.
 * ``prometheus_text`` / ``metrics_snapshot`` / ``perfetto_trace`` — pure
@@ -27,6 +29,7 @@ taps-only design rationale.
 """
 from .adapters import Observability, ObsConfig, ensure
 from .exporters import metrics_snapshot, perfetto_trace, prometheus_text
+from .phases import PhaseSpans
 from .registry import (
     DEFAULT_TIME_BUCKETS,
     Counter,
@@ -40,6 +43,7 @@ __all__ = [
     "Observability",
     "ObsConfig",
     "ensure",
+    "PhaseSpans",
     "MetricsRegistry",
     "Counter",
     "Gauge",

@@ -21,9 +21,9 @@ Design constraints (see docs/observability.md):
   based, so virtual-clock determinism is preserved).  The obs-on/obs-off
   throughput ratio is gated >= 0.97x in benchmarks/bench_obs.py.
 * **Deterministic on virtual clocks** — nothing wall-clock enters the
-  registry unless the tap was attached with ``clock="wall"`` (crossmatch)
-  or feeds from real I/O (journal fsync), so simulate/serving snapshots
-  are run-to-run identical.
+  registry unless the loop was attached with ``clock="wall"`` (crossmatch:
+  phase spans, ``obs/phases.py``) or feeds from real I/O (journal fsync),
+  so simulate/serving snapshots are run-to-run identical.
 """
 from __future__ import annotations
 
@@ -32,6 +32,7 @@ from time import perf_counter
 from typing import Optional
 
 from .exporters import metrics_snapshot, perfetto_trace, prometheus_text
+from .phases import PhaseSpans
 from .registry import DEFAULT_TIME_BUCKETS, MetricsRegistry
 from .tracer import ControlExplain, RoundTracer
 
@@ -86,25 +87,56 @@ class Observability:
             if self.config.trace else None
         )
         self.explain = ControlExplain(limit=self.config.explain_limit)
+        # Origin of wall-clock span times (perf_counter seconds).
+        self.epoch = perf_counter()
         self._steal_m = None
         self._journal_m = None
+        self._h2d = None  # [source, last reading, counter]
 
     # -- attach points -----------------------------------------------------
     def attach_loop(
         self, loop, *, track: int = 0, clock: str = "virtual",
-        name: Optional[str] = None,
+        name: Optional[str] = None, h2d_bytes=None,
     ) -> "_LoopTap":
         """Chain a metrics/tracing tap onto ``loop`` via ``add_round_tap``.
 
-        ``clock="virtual"`` stamps spans on the loop's simulated clock;
-        ``clock="wall"`` (crossmatch/daemon) uses ``perf_counter`` marks
-        between taps, which additionally measures host-side select time.
+        ``clock="virtual"`` stamps round spans on the loop's simulated
+        clock from the tap.  ``clock="wall"`` (crossmatch) also sets
+        ``loop.phases`` to a :class:`PhaseSpans`: the round span and its
+        measured phases are then taken inside the round, on
+        ``perf_counter``.
+
+        ``h2d_bytes`` is a process-wide running total of the bytes handed
+        to the device (``kernels/crossmatch/ops.py`` ``h2d_bytes``); each
+        round adds its change to ``liferaft_h2d_bytes_total``.  The first
+        one attached is kept.
         """
         tap = _LoopTap(self, loop, int(track), wall=(clock == "wall"))
         loop.add_round_tap(tap)
+        if clock == "wall":
+            loop.phases = PhaseSpans(self, track)
+        if h2d_bytes is not None and self._h2d is None:
+            self._h2d = [
+                h2d_bytes, h2d_bytes(),
+                self.registry.counter(
+                    "liferaft_h2d_bytes_total",
+                    "Bytes of the padded operands handed to the jitted "
+                    "cross-match programs",
+                ),
+            ]
         if self.tracer is not None:
             self.tracer.name_track(track, name or f"shard-{track}")
         return tap
+
+    def note_h2d(self) -> None:
+        """Add the watched byte total's change since the last call."""
+        h = self._h2d
+        if h is None:
+            return
+        n = h[0]()
+        if n != h[1]:
+            h[2].inc(n - h[1])
+            h[1] = n
 
     def note_steal(self, ev) -> None:
         """``on_steal`` tap: one work-steal migration (reads ``ev`` only)."""
@@ -226,7 +258,7 @@ class Observability:
         }
         if self.tracer is not None:
             out["trace"] = {
-                "rounds": len(self.tracer.rounds),
+                "rounds": self.tracer.count("round"),
                 "steals": len(self.tracer.steals),
                 "dropped": self.tracer.dropped,
                 "tracks": self.tracer.tracks(),
@@ -251,9 +283,9 @@ class _LoopTap:
         "obs", "loop", "track", "wall", "tracer", "explain",
         "age_every", "_round_i",
         "m_rounds", "m_buckets", "m_dev", "h_cost", "h_stall", "h_exec",
-        "h_select", "h_wall", "g_hit", "_cache", "_cache_m", "_cache_last",
+        "g_hit", "_cache", "_cache_m", "_cache_last",
         "_dev_last", "g_vec", "_vec_last", "_tvec_last",
-        "m_spill", "m_spill_bytes", "_tenant_m", "_epoch", "_mark",
+        "m_spill", "m_spill_bytes", "_tenant_m",
     )
 
     def __init__(self, obs: Observability, loop, track: int, wall: bool):
@@ -296,17 +328,6 @@ class _LoopTap:
             "Execute portion of the round (cost - stall)",
             track=t,
         )
-        self.h_select = reg.histogram(
-            "liferaft_round_select_seconds",
-            "Measured host-side select/plan overhead (wall-clock taps "
-            "only; the virtual clock prices selection at zero)",
-            track=t,
-        ) if wall else None
-        self.h_wall = reg.histogram(
-            "liferaft_round_wall_seconds",
-            "Wall time between consecutive rounds (wall-clock taps only)",
-            track=t,
-        ) if wall else None
         self.g_hit = reg.gauge(
             "liferaft_cache_hit_ratio", "Cumulative cache hit rate",
             track=t,
@@ -372,8 +393,6 @@ class _LoopTap:
             ),
         )
         self._tenant_m: dict = {}
-        self._epoch = perf_counter() if wall else 0.0
-        self._mark = 0.0
 
     def _cache_snapshot(self):
         st = self._cache
@@ -427,32 +446,18 @@ class _LoopTap:
         if self._round_i % self.age_every == 0:
             self._sample_tenants()
         tr = self.tracer
-        if tr is None:
+        if tr is None or self.wall:
+            # Wall rounds are stored by the loop's PhaseSpans, which saw
+            # the round from its start.
             return
-        if self.wall:
-            now = perf_counter() - self._epoch
-            wall_dur = now - self._mark
-            sel = max(0.0, wall_dur - cost)
-            if self.h_select is not None:
-                self.h_select.observe(sel)
-                self.h_wall.observe(wall_dur)
-            # Wall spans: the measured interval, with the select child the
-            # slice the cost model cannot see.  Model stall/execute don't
-            # nest on the wall axis, so they ride in args via the round
-            # histograms instead of as children.
-            tr.note_round(
-                self.track, self._mark, wall_dur,
-                (("select", sel),) if sel > 0.0 else (),
-                ndec,
-            )
-            self._mark = now
-        else:
-            t1 = loop.clock  # the round just advanced it by cost
-            children = (
-                (("prefetch_stall", stall), ("execute", exe))
-                if stall else (("execute", exe),)
-            )
-            tr.note_round(self.track, t1 - cost, cost, children, ndec)
+        t1 = loop.clock  # the round just advanced it by cost
+        children = (
+            (("prefetch_stall", 0.0, stall), ("execute", stall, exe))
+            if stall else (("execute", 0.0, exe),)
+        )
+        tr.note_span(
+            self.track, "round", t1 - cost, cost, children, {"buckets": ndec}
+        )
 
     # -- slow paths (change- or sample-triggered) --------------------------
     def _note_spill(self, changed) -> None:

@@ -8,9 +8,10 @@ runs (sorted iteration everywhere; see ``registry.MetricsRegistry``).
 The Perfetto export is the Chrome trace-event JSON object format
 (``{"traceEvents": [...]}``, timestamps in microseconds): one named thread
 per track (an S-track timeline for a sharded run), complete ``"X"`` spans
-for rounds and their latency-breakdown children, and flow events
-(``"s"``/``"f"``, ``cat == "steal"``) drawing each work-steal migration as
-an arrow from the victim's track to the thief's.  Loadable directly in
+for rounds and submits and their children (each at its own start offset,
+so a wall round shows its measured phases and the gaps between them), and
+flow events (``"s"``/``"f"``, ``cat == "steal"``) drawing each work-steal
+migration as an arrow from the victim's track to the thief's.  Loadable directly in
 https://ui.perfetto.dev or chrome://tracing.
 """
 from __future__ import annotations
@@ -82,23 +83,21 @@ def perfetto_trace(tracer, *, process_name: str = "liferaft") -> dict:
             "ph": "M", "name": "thread_sort_index", "pid": 1, "tid": track,
             "args": {"sort_index": track},
         })
-    for track, t0, dur, children, n_buckets in tracer.rounds:
+    for track, name, t0, dur, children, args in tracer.spans:
         events.append({
-            "ph": "X", "name": "round", "cat": "round",
+            "ph": "X", "name": name, "cat": name,
             "pid": 1, "tid": track,
             "ts": t0 * _US, "dur": dur * _US,
-            "args": {"buckets": n_buckets},
+            "args": args,
         })
-        t = t0
-        for cname, cdur in children:
+        for cname, off, cdur in children:
             if cdur <= 0.0:
                 continue
             events.append({
-                "ph": "X", "name": cname, "cat": "round",
+                "ph": "X", "name": cname, "cat": name,
                 "pid": 1, "tid": track,
-                "ts": t * _US, "dur": cdur * _US,
+                "ts": (t0 + off) * _US, "dur": cdur * _US,
             })
-            t += cdur
     for i, (victim, thief, t, bucket_id, n_units) in enumerate(tracer.steals):
         ts = t * _US
         args = {"bucket": bucket_id, "units": n_units}

@@ -345,17 +345,20 @@ class ServiceDaemon:
             journal_dir, segment_bytes=segment_bytes, kind=host.kind
         )
         self.obs = None
+        self._phases = None
         if obs:
             # Lazy import: the default (obs off) never touches repro.obs.
             # The daemon's contribution is the journal append/fsync
-            # latency tap, admission verdict counters, and the served
+            # latency tap, admission verdict counters, the submit and
+            # decompose phase spans, and the served
             # metrics_text/metrics_snapshot endpoints; to also see the
             # engine's round metrics, construct the engine with the same
             # Observability instance.
-            from ..obs import ensure as _obs_ensure
+            from ..obs import PhaseSpans, ensure as _obs_ensure
 
             self.obs = _obs_ensure(obs)
             self.obs.attach_journal(self.journal)
+            self._phases = PhaseSpans(self.obs)
         # Full in-memory decision log (same entries the journal holds,
         # including rounds recovered by replay) — diffable against a
         # golden via ``diff_entries``.
@@ -435,8 +438,20 @@ class ServiceDaemon:
         acked — the engine is not touched).  Raises
         :class:`~repro.core.control.AdmissionRejected` on quota (journaled
         before raising; resubmits re-raise the cached rejection unless
-        ``retry=True``)."""
+        ``retry=True``).  With obs on, each call is one ``submit`` span
+        carrying the key, with a ``decompose`` child around the engine's
+        intake."""
         key = key if key is not None else self.host.item_key(item)
+        ph = self._phases
+        if ph is None:
+            return self._submit(item, key, retry)
+        ph.begin_submit(key)
+        try:
+            return self._submit(item, key, retry)
+        finally:
+            ph.end_submit()
+
+    def _submit(self, item, key: str, retry: bool) -> dict:
         if key in self.acked:
             return {"key": key, "status": "duplicate"}
         cached = self.rejected.get(key)
@@ -474,7 +489,12 @@ class ServiceDaemon:
             {"type": "submit", "key": key, "item": self.host.encode_item(item)},
             sync=True,
         )
+        ph = self._phases
+        if ph is not None:
+            ph.phase("decompose")
         self.host.submit(item)
+        if ph is not None:
+            ph.end()
         self.acked[key] = self.host.encode_item(item)
         self.rejected.pop(key, None)
         return {"key": key, "status": "acked"}

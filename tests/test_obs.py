@@ -17,14 +17,22 @@ pin each clause of that contract:
   * daemon endpoints — journal append/fsync histograms, admission
     verdict counters, metrics_text/metrics_snapshot, and their empty
     obs-off fallbacks;
-  * ControlExplain — vector changes carry the trigger-signal reason.
+  * ControlExplain — vector changes carry the trigger-signal reason;
+  * wall-clock phase spans — a served cross-match round, on each executor
+    path, is one span whose children are exactly its measured phases,
+    inside the round and not overlapping; a submit is one span with a
+    decompose child; both land in the profiler's trace as
+    ``liferaft.*`` annotations; the host-to-device byte counter equals
+    the padded operands' bytes.
 """
 import json
 import os
 import pathlib
 import subprocess
 import sys
+import tempfile
 
+import numpy as np
 import pytest
 
 import replay
@@ -36,6 +44,7 @@ from repro.core import (
 from repro.obs import MetricsRegistry, Observability
 from repro.serving import (
     AdapterSpec,
+    CrossMatchHost,
     LifeRaftEngine,
     Request,
     ServeConfig,
@@ -287,3 +296,237 @@ def test_control_explain_names_the_trigger_signal():
     # The message leads with the field's trigger signal (docs/adaptive.md).
     alpha_msgs = [e["message"] for e in events if e["field"] == "alpha"]
     assert any("saturation" in m for m in alpha_msgs)
+
+
+# ------------------------------------------------------- wall-clock phase spans
+ROUND_PHASES = {
+    "select", "fetch", "gather", "launch", "readback", "route", "complete",
+}
+# Executor path -> CrossMatchEngine settings that take it.
+PATHS = {
+    "single": dict(fuse_k=1),
+    "fused": dict(fuse_k=3),
+    "per_predicate": dict(fuse_k=2),
+    "shared": dict(fuse_k=2, shared_plan=True, share_width=2),
+}
+_SERVED = {}
+
+
+def _catalog():
+    from repro.crossmatch import make_catalog
+
+    if "catalog" not in _SERVED:
+        _SERVED["catalog"] = make_catalog(
+            n_objects=2_000, objects_per_bucket=100, htm_level=6, seed=17
+        )
+    return _SERVED["catalog"]
+
+
+def _served(path):
+    """A CrossMatchEngine behind a ServiceDaemon, sharing one
+    Observability, that served a small trace down one executor path:
+    (obs, engine, rounds served, queries submitted)."""
+    if path not in _SERVED:
+        from repro.crossmatch import CrossMatchEngine, TraceConfig, make_trace
+
+        catalog = _catalog()
+        trace = make_trace(
+            catalog,
+            TraceConfig(n_queries=8, arrival_rate=2.0, objects_median=40,
+                        seed=19),
+        )
+        if path in ("per_predicate", "shared"):
+            rng = np.random.default_rng(5)
+            for q in trace:
+                q.meta["radius"] = float(rng.choice([2e-3, 4e-3, 8e-3]))
+                q.meta["mag_cut"] = float(rng.choice([23.0, 24.0]))
+        obs = Observability()
+        eng = CrossMatchEngine(
+            catalog, match_radius_rad=4e-3, obs=obs, **PATHS[path]
+        )
+        with tempfile.TemporaryDirectory() as journal:
+            daemon = ServiceDaemon(CrossMatchHost(eng), journal, obs=obs)
+            for q in trace:
+                daemon.submit(q)
+            n_rounds = daemon.pump()
+            daemon.close()
+        _SERVED[path] = (obs, eng, n_rounds, len(trace))
+    return _SERVED[path]
+
+
+def _phase_series(obs, phase):
+    m = obs.snapshot()["metrics"]["liferaft_phase_seconds"]["series"]
+    return next(s for s in m if s["labels"]["phase"] == phase)
+
+
+@pytest.mark.parametrize("path", sorted(PATHS))
+class TestPhaseSpans:
+    def test_round_children_are_the_phases(self, path):
+        obs, eng, n_rounds, _ = _served(path)
+        rounds = [s for s in obs.tracer.spans if s[1] == "round"]
+        assert len(rounds) == n_rounds == eng.loop.dispatches > 0
+        for track, _, t0, dur, children, args in rounds:
+            assert track == 0
+            assert {c[0] for c in children} == ROUND_PHASES
+            assert children[0][0] == "select"
+            assert children[-1][0] == "complete"
+            assert len(args["buckets"]) >= 1
+            end = 0.0
+            for name, off, cdur in children:
+                assert cdur >= 0.0
+                assert off >= end, f"{name} overlaps the child before it"
+                end = off + cdur
+            assert end <= dur
+
+    def test_one_select_per_round(self, path):
+        obs, eng, n_rounds, _ = _served(path)
+        assert _phase_series(obs, "select")["count"] == n_rounds
+        assert _phase_series(obs, "complete")["count"] == n_rounds
+        m = obs.snapshot()["metrics"]
+        wall = m["liferaft_round_wall_seconds"]["series"][0]
+        assert wall["count"] == n_rounds
+        # The rounds' children are the phase observations of the rounds.
+        spans = [s for s in obs.tracer.spans if s[1] == "round"]
+        for phase in ROUND_PHASES:
+            total = sum(c[2] for s in spans for c in s[4] if c[0] == phase)
+            assert _phase_series(obs, phase)["sum"] == pytest.approx(total)
+        assert "liferaft_round_select_seconds" not in m
+
+    def test_submit_spans_and_fsync(self, path):
+        obs, _, _, n_queries = _served(path)
+        subs = [s for s in obs.tracer.spans if s[1] == "submit"]
+        assert len(subs) == n_queries
+        for _, _, _, dur, children, args in subs:
+            assert args["key"].startswith("q-")
+            assert [c[0] for c in children] == ["decompose"]
+            assert 0.0 <= children[0][1] and sum(children[0][1:]) <= dur
+        assert _phase_series(obs, "decompose")["count"] == n_queries
+        m = obs.snapshot()["metrics"]
+        fsync = m["liferaft_journal_fsync_seconds"]["series"][0]
+        assert fsync["count"] == n_queries and fsync["sum"] > 0.0
+
+    def test_perfetto_shows_the_phases(self, path):
+        obs = _served(path)[0]
+        evs = json.loads(json.dumps(obs.perfetto()))["traceEvents"]
+        rounds = [e for e in evs if e["ph"] == "X" and e["name"] == "round"]
+        kids = [e for e in evs if e["ph"] == "X" and e["cat"] == "round"
+                and e["name"] != "round"]
+        assert {e["name"] for e in kids} <= ROUND_PHASES
+        assert {"select", "launch", "complete"} <= {e["name"] for e in kids}
+        for k in kids:
+            assert any(r["ts"] <= k["ts"] and k["ts"] + k["dur"]
+                       <= r["ts"] + r["dur"] + 1e-3 for r in rounds)
+        assert any(e["ph"] == "X" and e["name"] == "decompose" for e in evs)
+
+
+def test_virtual_round_children_partition_the_round():
+    """On the cost model's clock the children are laid end to end from
+    the round's start and fill it."""
+    obs, _ = _obs_run("sim_prefetch")
+    rounds = [s for s in obs.tracer.spans if s[1] == "round"]
+    assert rounds
+    for _, _, t0, dur, children, _ in rounds:
+        assert children[0][1] == 0.0
+        for (_, off, cdur), nxt in zip(children, children[1:] + ((None, None, None),)):
+            if nxt[1] is not None:
+                assert nxt[1] == pytest.approx(off + cdur)
+        assert sum(c[2] for c in children) == pytest.approx(dur)
+
+
+def _pow2(n):
+    return 1 << (max(n, 8) - 1).bit_length()
+
+
+@pytest.mark.parametrize("core", ["single", "fused", "shared"])
+def test_h2d_bytes_of_one_known_call(core):
+    """``ops.h2d_bytes`` grows by the nbytes of the padded operands the
+    wrapper hands to its jitted core: (rows, 8) f32 coordinates, and f32
+    segment ids and thresholds, each padded to a power of two."""
+    from repro.kernels.crossmatch import ops
+
+    n, m = 37, 5
+    rng = np.random.default_rng(0)
+    bucket = rng.normal(size=(n, 3)).astype(np.float32)
+    probes = bucket[:m]
+    bseg, pseg = np.zeros(n, np.int32), np.zeros(m, np.int32)
+    before = ops.h2d_bytes()
+    if core == "single":
+        ops.crossmatch(bucket, probes, 0.5)
+        want = (_pow2(n) + _pow2(m)) * 8 * 4
+    elif core == "fused":
+        ops.crossmatch_fused(bucket, probes, bseg, pseg, 0.5)
+        want = (_pow2(n) + _pow2(m)) * 9 * 4
+    else:
+        ops.crossmatch_shared(bucket, probes, bseg, pseg,
+                              np.full(m, 0.5, np.float32))
+        want = (_pow2(n) * 9 + _pow2(m) * 10) * 4
+    assert ops.h2d_bytes() - before == want
+
+
+def test_h2d_counter_equals_the_operands_of_a_served_call():
+    """One query of five objects inside one bucket: one round, one
+    single-bucket call, and ``liferaft_h2d_bytes_total`` holds exactly
+    the bytes of its two padded operands."""
+    from repro.core.workload import Query
+    from repro.crossmatch import CrossMatchEngine
+
+    catalog = _catalog()
+    b = 3
+    payload = catalog.store.read(b)
+    rows = slice(40, 45)
+    keys = payload["htm"][rows]
+    q = Query(query_id=0, arrival_time=0.0, keys_lo=keys, keys_hi=keys,
+              payload={"positions": payload["positions"][rows]})
+    obs = Observability()
+    eng = CrossMatchEngine(catalog, match_radius_rad=4e-3, obs=obs)
+    eng.run([q])
+    assert eng.loop.dispatches == 1
+    n = len(payload["positions"])
+    want = (_pow2(n) + _pow2(5)) * 8 * 4
+    m = obs.snapshot()["metrics"]["liferaft_h2d_bytes_total"]["series"][0]
+    assert m["value"] == want
+
+
+def test_phase_spans_land_in_the_profiler_trace(tmp_path):
+    """The spans are ``liferaft.*`` TraceAnnotations in the profiler's own
+    trace: each round holds its select/launch/complete, each submit its
+    decompose, and the round carries its index and buckets."""
+    import jax
+    from jax.profiler import ProfileData
+    from repro.crossmatch import CrossMatchEngine, TraceConfig, make_trace
+
+    catalog = _catalog()
+    trace = make_trace(catalog, TraceConfig(
+        n_queries=3, arrival_rate=2.0, objects_median=20, seed=23))
+    obs = Observability()
+    eng = CrossMatchEngine(catalog, match_radius_rad=4e-3, fuse_k=2, obs=obs)
+    daemon = ServiceDaemon(CrossMatchHost(eng), tmp_path / "j", obs=obs)
+    jax.profiler.start_trace(str(tmp_path / "trace"))
+    try:
+        for q in trace:
+            daemon.submit(q)
+        daemon.pump()
+    finally:
+        jax.profiler.stop_trace()
+        daemon.close()
+    (path,) = (tmp_path / "trace").glob("**/*.xplane.pb")
+    events = [
+        (ev.name, ev.start_ns, ev.start_ns + ev.duration_ns, dict(ev.stats))
+        for plane in ProfileData.from_file(str(path)).planes
+        for line in plane.lines
+        for ev in line.events
+        if ev.name.startswith("liferaft.")
+    ]
+    names = {e[0] for e in events}
+    assert {f"liferaft.{p}" for p in ROUND_PHASES} <= names
+    assert {"liferaft.round", "liferaft.submit", "liferaft.decompose"} <= names
+    rounds = [e for e in events if e[0] == "liferaft.round"]
+    assert len(rounds) == eng.loop.dispatches
+    assert sorted(r[3]["round"] for r in rounds) == list(range(len(rounds)))
+    assert all("buckets" in r[3] for r in rounds)
+    for name, s, e, _ in events:
+        parent = "liferaft.submit" if name == "liferaft.decompose" else (
+            "liferaft.round" if name[9:] in ROUND_PHASES else None)
+        if parent is not None:
+            assert any(p[0] == parent and p[1] <= s and e <= p[2]
+                       for p in events), name
