@@ -302,8 +302,10 @@ class CrossMatchEngine:
         With ``loop.phases`` set, every path records the same phase spans:
         ``fetch`` per bucket, ``gather`` (probe rows, f32 casts, segment
         and operand concatenation), ``launch`` (the ``ops.crossmatch*``
-        call: padding, transfer, dispatch and any compile), ``readback``
-        (``np.asarray`` of the outputs) and ``route``."""
+        call: padding, transfer, dispatch, any compile, the device's work
+        and the copy of the outputs back as host arrays), ``readback``
+        (``np.asarray`` of those host arrays, about nothing) and
+        ``route``."""
         if self.shared_plan:
             return self.execute_shared(decisions, vector)
         if self._has_query_predicates:

@@ -7,9 +7,9 @@ Handles padding and dispatch for two entry points:
                         (*shape bucketing*), so a query trace triggers
                         O(log max_M) jit compilations instead of one per
                         distinct batch size; ``jit_cache_size()`` exposes
-                        the compile count for benchmarks, ``h2d_bytes()``
-                        the bytes of the padded operands handed to the
-                        jitted cores.
+                        the cores' compile count for benchmarks,
+                        ``h2d_bytes()`` the bytes of the padded operands
+                        handed to the jitted cores.
 ``crossmatch_fused``  — k buckets in ONE device call: payloads and probe
                         batches are concatenated with segment ids and the
                         join is evaluated as a segment-masked matmul
@@ -25,6 +25,12 @@ argmax nor inflate ``n_cand`` — including when ``cos_thr <= 0`` (match
 radius >= pi/2), which used to count every zero-padded row.  The fused
 path gets the same guarantee from its segment mask (padded rows carry
 segment ``PAD_SEG``, which matches no real segment).
+
+Outputs come back as host numpy arrays: each wrapper fetches the core's
+three padded outputs in one ``jax.device_get`` (which waits for the
+device) and cuts them to the real probe count on the host.  No device op
+runs on the outputs, so a probe count not seen before compiles nothing
+beyond its power-of-two core shape.
 
 Pallas runs compiled on a TPU and in interpret mode on the CPU.  The
 wrappers resolve ``interpret`` from the backend, outside ``jit``, when the
@@ -139,6 +145,16 @@ def _host_prepare(bucket, probes):
     return b8, p8, n_true, m_true
 
 
+def _host_outputs(out, n_true: int, m_true: int):
+    """Fetch a core's padded (best_idx, best_dot, n_cand) in one transfer
+    and cut them to the ``m_true`` real probes in numpy; ``best_idx`` is
+    clamped to the real bucket rows (padded rows cannot win, but
+    belt-and-braces).  Eager device slices would compile once per
+    distinct ``m_true``."""
+    idx, dot, cnt = jax.device_get(out)
+    return np.minimum(idx[:m_true], max(n_true - 1, 0)), dot[:m_true], cnt[:m_true]
+
+
 @functools.partial(
     jax.jit, static_argnames=("cos_thr", "use_pallas", "bm", "bn", "band", "interpret")
 )
@@ -169,7 +185,8 @@ def crossmatch(
 ):
     """Cross-match ``probes`` against ``bucket`` (both (?,3) unit vectors).
 
-    Returns (best_idx, best_dot, n_cand), each of length len(probes).
+    Returns (best_idx, best_dot, n_cand) as host numpy arrays, each of
+    length len(probes).
     ``use_pallas=False`` uses the jnp reference path;
     ``use_pallas=True`` runs the Pallas kernel, compiled on a TPU and
     interpreted on the CPU unless ``interpret`` says otherwise.
@@ -182,12 +199,10 @@ def crossmatch(
     interpret = _resolve_interpret(interpret, use_pallas)
     bucket8, probes8, n_true, m_true = _host_prepare(bucket, probes)
     _h2d[0] += bucket8.nbytes + probes8.nbytes
-    idx, dot, cnt = _crossmatch_jit(
+    out = _crossmatch_jit(
         bucket8, probes8, float(cos_thr), use_pallas, bm, bn, band, interpret
     )
-    # Padded rows cannot win (marker dot -2), but clamp for belt-and-braces.
-    idx = jnp.minimum(idx[:m_true], max(n_true - 1, 0))
-    return idx, dot[:m_true], cnt[:m_true]
+    return _host_outputs(out, n_true, m_true)
 
 
 def h2d_bytes() -> int:
@@ -198,7 +213,9 @@ def h2d_bytes() -> int:
 
 def jit_cache_size() -> int:
     """Total shapes compiled across the single-bucket, fused, and
-    shared-plan cores (benchmarks gate this staying O(log max batch))."""
+    shared-plan cores (benchmarks gate this staying O(log max batch)).
+    Counts the cores' cache entries only: eager device work outside them
+    would compile without showing here (the wrappers run none)."""
     return int(
         _crossmatch_jit._cache_size()
         + _crossmatch_fused_jit._cache_size()
@@ -266,8 +283,8 @@ def crossmatch_shared(
     cache entries.  Thresholds must lie in (-2, 1]; real cosines do, and
     padded probe rows get ``_PAD_THR`` (+2, passes nothing).
 
-    Returns (best_idx, best_dot, n_cand) of length len(probes); best_idx
-    indexes the concatenated bucket array.
+    Returns (best_idx, best_dot, n_cand) as host numpy arrays of length
+    len(probes); best_idx indexes the concatenated bucket array.
     """
     interpret = _resolve_interpret(interpret, use_pallas)
     bucket8, probes8, n_true, m_true = _host_prepare(bucket, probes)
@@ -283,12 +300,11 @@ def crossmatch_shared(
     _h2d[0] += (
         bucket8.nbytes + probes8.nbytes + bseg.nbytes + pseg.nbytes + thr.nbytes
     )
-    idx, dot, cnt = _crossmatch_shared_jit(
+    out = _crossmatch_shared_jit(
         bucket8, probes8, jnp.asarray(bseg), jnp.asarray(pseg), jnp.asarray(thr),
         use_pallas, bm, bn, interpret,
     )
-    idx = jnp.minimum(idx[:m_true], max(n_true - 1, 0))
-    return idx, dot[:m_true], cnt[:m_true]
+    return _host_outputs(out, n_true, m_true)
 
 
 def crossmatch_fused(
@@ -313,6 +329,9 @@ def crossmatch_fused(
 
     Shapes are padded to powers of two (padded rows get segment
     ``PAD_SEG``), bounding compile count over a trace.
+
+    Returns (best_idx, best_dot, n_cand) as host numpy arrays of length
+    len(probes).
     """
     interpret = _resolve_interpret(interpret, use_pallas)
     bucket8, probes8, n_true, m_true = _host_prepare(bucket, probes)
@@ -325,9 +344,8 @@ def crossmatch_fused(
     pseg = np.full(probes8.shape[0], PAD_SEG, np.float32)
     pseg[:m_true] = np.asarray(probe_seg, np.float32)
     _h2d[0] += bucket8.nbytes + probes8.nbytes + bseg.nbytes + pseg.nbytes
-    idx, dot, cnt = _crossmatch_fused_jit(
+    out = _crossmatch_fused_jit(
         bucket8, probes8, jnp.asarray(bseg), jnp.asarray(pseg),
         float(cos_thr), use_pallas, bm, bn, interpret,
     )
-    idx = jnp.minimum(idx[:m_true], max(n_true - 1, 0))
-    return idx, dot[:m_true], cnt[:m_true]
+    return _host_outputs(out, n_true, m_true)

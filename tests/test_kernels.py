@@ -100,7 +100,9 @@ class TestCrossmatch:
         np.testing.assert_allclose(np.asarray(d), np.asarray(rd), rtol=1e-6)
 
     def test_shape_bucketing_bounds_compiles(self):
-        """Sweeping probe counts must reuse O(log M) compiled shapes."""
+        """Sweeping probe counts must reuse O(log M) compiled shapes.
+        ``jit_cache_size`` counts the cores' entries only; the wrappers'
+        own compiles are counted in ``test_new_probe_count_compiles_nothing``."""
         bkt = _unit(500, 11)
         thr = float(np.cos(0.05))
         before = cm_ops.jit_cache_size()
@@ -158,6 +160,51 @@ class TestCrossmatchFused:
         )
         assert (np.asarray(c) == 0).all()
         assert (np.asarray(d) <= -1.5).all()  # masked sentinel, never a match
+
+
+@pytest.mark.parametrize("wrapper", ["crossmatch", "crossmatch_fused",
+                                     "crossmatch_shared"])
+def test_new_probe_count_compiles_nothing(wrapper):
+    """Probe counts inside one power-of-two bucket share one compiled core,
+    and nothing else compiles: JAX's backend-compile events are counted
+    for every call after the first.  Outputs keep the true lengths and
+    ``best_idx`` stays inside the real bucket rows."""
+    event = "/jax/core/compile/backend_compile_duration"
+    compiles = []
+
+    def listen(name, duration, **_):
+        if name == event:
+            compiles.append(duration)
+
+    n, thr = 300, float(np.cos(0.05))
+    bkt = _unit(n, 21)
+    bseg = np.repeat(np.arange(3), [100, 100, 100])
+    fn = getattr(cm_ops, wrapper)
+    # Counts of the 2048 bucket, distinct per wrapper so no case reuses
+    # another's programs.
+    shift = ("crossmatch", "crossmatch_fused", "crossmatch_shared").index(wrapper)
+    counts = []
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    try:
+        for m in (1500, 1601, 1723, 1850, 1997, 2045):
+            m += shift
+            prb = _unit(m, m)
+            pseg = np.arange(m) % 3
+            before = len(compiles)
+            if wrapper == "crossmatch":
+                out = fn(bkt, prb, thr)
+            elif wrapper == "crossmatch_fused":
+                out = fn(bkt, prb, bseg, pseg, thr)
+            else:
+                out = fn(bkt, prb, bseg, pseg, np.full(m, thr, np.float32))
+            jax.block_until_ready(out)
+            counts.append(len(compiles) - before)
+            idx, dot, cnt = out
+            assert len(idx) == len(dot) == len(cnt) == m
+            assert 0 <= idx.min() and idx.max() < n
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listen)
+    assert counts[1:] == [0] * (len(counts) - 1), counts
 
 
 # ------------------------------------------------------------------ grouped matmul
