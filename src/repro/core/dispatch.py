@@ -122,7 +122,11 @@ class DispatchLoop:
         self._occ_by_tenant: dict[str, float] = {}
         self._shared_occ = 0.0  # last shared-plan round's query fill
         self._shared_occ_sum = 0.0  # occupancy-weighted shared-call total
-        self._shared_calls = 0  # shared-plan device calls (occupancy known)
+        # Shared-plan device calls (occupancy known), the queries they
+        # carried and their distinct thresholds, each summed over calls.
+        self.shared_calls = 0
+        self.shared_queries = 0
+        self.shared_classes = 0
         self._dev_noted = False  # executor reported its own device calls
         self.prefetch = prefetch
         # Set by the shard tier (core/shard.py) before a round: the global
@@ -161,28 +165,38 @@ class DispatchLoop:
 
     # -- executor-side sensor ----------------------------------------------------
     def note_device_dispatches(
-        self, n: int, shared_occupancy: Optional[float] = None
+        self,
+        n: int,
+        shared_occupancy: Optional[float] = None,
+        queries: int = 0,
+        classes: int = 0,
     ) -> None:
         """Executor callback: the round just executed issued ``n`` device
         calls (a shared plan issues fewer than one per bucket or per
         predicate class).  ``shared_occupancy`` is the query fill of those
         calls — queries / (chunks * share_width) — and feeds the
-        share_width AIMD law via telemetry.  Executors that never call
-        this get the legacy accounting of one device call per round."""
-        self.device_dispatches += max(0, int(n))
+        share_width AIMD law via telemetry; it marks the calls as shared,
+        and ``queries`` (carried, summed over the calls) and ``classes``
+        (distinct thresholds of each call, summed) are then added to the
+        shared-plan counters.  Executors that never call this get the
+        legacy accounting of one device call per round."""
+        n = max(0, int(n))
+        self.device_dispatches += n
         self._dev_noted = True
         if shared_occupancy is not None:
             self._shared_occ = min(1.0, max(0.0, shared_occupancy))
-            self._shared_occ_sum += self._shared_occ * max(0, int(n))
-            self._shared_calls += max(0, int(n))
+            self._shared_occ_sum += self._shared_occ * n
+            self.shared_calls += n
+            self.shared_queries += max(0, int(queries))
+            self.shared_classes += max(0, int(classes))
 
     @property
     def shared_batch_occupancy(self) -> float:
         """Mean query fill across all shared-plan device calls (0.0 when
         the executor never reported one)."""
-        if self._shared_calls <= 0:
+        if self.shared_calls <= 0:
             return 0.0
-        return self._shared_occ_sum / self._shared_calls
+        return self._shared_occ_sum / self.shared_calls
 
     # -- intake-side sensor -----------------------------------------------------
     def observe_arrival(self, t: float) -> None:

@@ -547,6 +547,7 @@ class CrossMatchEngine:
         best_dot = np.zeros(len(owners_cat), np.float32)
         n_cand = np.zeros(len(owners_cat), np.int64)
         chunks = [qids[i : i + width] for i in range(0, len(qids), width)] or [[]]
+        n_classes = 0  # distinct thresholds of each call, summed
         for chunk in chunks:
             if ph is not None:
                 ph.phase("gather")
@@ -556,8 +557,9 @@ class CrossMatchEngine:
             probes_rows, pseg_rows, thr_rows = (
                 probes_cat[rows], pseg_cat[rows], thr_row[rows]
             )
+            classes = len({np.float32(self._pred_of(q)[0]) for q in chunk})
             if ph is not None:
-                ph.phase("launch")
+                ph.phase("launch", queries=len(chunk), classes=classes)
             out = cm_ops.crossmatch_shared(
                 bucket_cat, probes_rows, bseg_cat, pseg_rows, thr_rows,
                 use_pallas=self.use_pallas,
@@ -568,10 +570,14 @@ class CrossMatchEngine:
                 np.asarray(a) for a in out
             )
             n_calls += 1
+            n_classes += classes
         occupancy = (
             len(qids) / (len(chunks) * width) if qids and chunks else 0.0
         )
-        self.loop.note_device_dispatches(n_calls, shared_occupancy=occupancy)
+        self.loop.note_device_dispatches(
+            n_calls, shared_occupancy=occupancy,
+            queries=len(qids), classes=n_classes,
+        )
 
         if ph is not None:
             ph.phase("route")

@@ -284,7 +284,8 @@ class _LoopTap:
         "age_every", "_round_i",
         "m_rounds", "m_buckets", "m_dev", "h_cost", "h_stall", "h_exec",
         "g_hit", "_cache", "_cache_m", "_cache_last",
-        "_dev_last", "g_vec", "_vec_last", "_tvec_last",
+        "_dev_last", "m_shared", "_shared_last", "g_vec", "_vec_last",
+        "_tvec_last",
         "m_spill", "m_spill_bytes", "_tenant_m",
     )
 
@@ -357,6 +358,19 @@ class _LoopTap:
         )
         self._cache_last = self._cache_snapshot()
         self._dev_last = loop.device_dispatches
+        # Shared-plan calls, the queries they carried and their distinct
+        # thresholds (DispatchLoop.shared_*, fed by the executor).
+        self.m_shared = tuple(
+            reg.counter(f"liferaft_shared_{what}_total", help_, track=t)
+            for what, help_ in (
+                ("calls", "Shared-plan device calls"),
+                ("queries", "Queries carried by shared-plan calls, summed "
+                 "over calls"),
+                ("predicate_classes", "Distinct thresholds in each "
+                 "shared-plan call, summed over calls"),
+            )
+        )
+        self._shared_last = self._shared_snapshot()
         self.g_vec = {
             f: reg.gauge(
                 f"liferaft_control_{f}",
@@ -394,6 +408,10 @@ class _LoopTap:
         )
         self._tenant_m: dict = {}
 
+    def _shared_snapshot(self):
+        loop = self.loop
+        return (loop.shared_calls, loop.shared_queries, loop.shared_classes)
+
     def _cache_snapshot(self):
         st = self._cache
         if st is None:
@@ -420,6 +438,11 @@ class _LoopTap:
         if dd != self._dev_last:
             self.m_dev.inc(dd - self._dev_last)
             self._dev_last = dd
+            cur = self._shared_snapshot()
+            for m, c, prev in zip(self.m_shared, cur, self._shared_last):
+                if c != prev:
+                    m.inc(c - prev)
+            self._shared_last = cur
         cur = self._cache_snapshot()
         if cur is not None:
             last = self._cache_last

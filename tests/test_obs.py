@@ -23,7 +23,11 @@ pin each clause of that contract:
     inside the round and not overlapping; a submit is one span with a
     decompose child; both land in the profiler's trace as
     ``liferaft.*`` annotations; the host-to-device byte counter equals
-    the padded operands' bytes.
+    the padded operands' bytes;
+  * shared-plan counters — calls, queries carried and distinct
+    thresholds equal what ``execute_shared`` issued (0 on the fused
+    path), and each shared ``launch`` span carries its call's queries
+    and classes.
 """
 import json
 import os
@@ -530,3 +534,111 @@ def test_phase_spans_land_in_the_profiler_trace(tmp_path):
         if parent is not None:
             assert any(p[0] == parent and p[1] <= s and e <= p[2]
                        for p in events), name
+
+
+# ------------------------------------------------------- shared-plan counters
+SHARED_COUNTERS = ("calls", "queries", "predicate_classes")
+
+
+def _counted_shared_serve(path, monkeypatch, obs, profile_dir=None):
+    """Serve a small trace down ``path`` with ``ops.crossmatch_shared`` and
+    ``execute_shared`` wrapped to tally what the shared plan issues: each
+    call, its distinct thresholds, and each round's distinct queries (one
+    chunk carries each).  Returns (engine, tally)."""
+    from repro.crossmatch import CrossMatchEngine, TraceConfig, make_trace
+    from repro.kernels.crossmatch import ops
+
+    issued = dict.fromkeys(SHARED_COUNTERS, 0)
+    real_kernel = ops.crossmatch_shared
+
+    def kernel(bucket, probes, bseg, pseg, thr, **kw):
+        issued["calls"] += 1
+        issued["predicate_classes"] += len(np.unique(np.asarray(thr, np.float32)))
+        return real_kernel(bucket, probes, bseg, pseg, thr, **kw)
+
+    monkeypatch.setattr(ops, "crossmatch_shared", kernel)
+    catalog = _catalog()
+    trace = make_trace(catalog, TraceConfig(
+        n_queries=10, arrival_rate=2.0, objects_median=40, seed=29))
+    if path == "shared":
+        rng = np.random.default_rng(7)
+        for q in trace:
+            q.meta["radius"] = float(rng.choice([2e-3, 4e-3, 8e-3]))
+            q.meta["mag_cut"] = float(rng.choice([23.0, 24.0]))
+    eng = CrossMatchEngine(catalog, match_radius_rad=4e-3, obs=obs, **PATHS[path])
+    real_execute = eng.execute_shared
+
+    def execute_shared(decisions, vector=None):
+        wm = eng.wm
+        issued["queries"] += len({
+            u.query_id for d in decisions
+            for u in wm.queue(d.bucket_id).units + wm.queue(d.bucket_id).spilled_units
+        })
+        return real_execute(decisions, vector)
+
+    eng.execute_shared = execute_shared
+    with tempfile.TemporaryDirectory() as journal:
+        daemon = ServiceDaemon(CrossMatchHost(eng), journal, obs=obs)
+        if profile_dir is not None:
+            import jax
+
+            jax.profiler.start_trace(str(profile_dir))
+        try:
+            for q in trace:
+                daemon.submit(q)
+            daemon.pump()
+        finally:
+            if profile_dir is not None:
+                jax.profiler.stop_trace()
+            daemon.close()
+    return eng, issued
+
+
+@pytest.mark.parametrize("path", ["shared", "fused"])
+def test_shared_plan_counters_equal_what_execute_shared_issued(path, monkeypatch):
+    obs = Observability()
+    eng, issued = _counted_shared_serve(path, monkeypatch, obs)
+    metrics = obs.snapshot()["metrics"]
+    got = {
+        c: metrics[f"liferaft_shared_{c}_total"]["series"][0]["value"]
+        for c in SHARED_COUNTERS
+    }
+    assert got == issued
+    loop = eng.loop
+    assert (loop.shared_calls, loop.shared_queries, loop.shared_classes) == tuple(
+        issued[c] for c in SHARED_COUNTERS)
+    if path == "shared":
+        # Chunks of two queries with three radii: some calls hold two
+        # thresholds, and every query rides in one call a round.
+        assert 0 < issued["calls"] < issued["predicate_classes"]
+        assert issued["calls"] <= issued["queries"] <= 2 * issued["calls"]
+        assert loop.device_dispatches == issued["calls"]
+    else:
+        assert issued == dict.fromkeys(SHARED_COUNTERS, 0)
+        assert loop.device_dispatches == loop.dispatches > 0
+    text = obs.prometheus()
+    for c in SHARED_COUNTERS:
+        assert f"liferaft_shared_{c}_total" in text
+
+
+def test_shared_launch_spans_carry_queries_and_classes(monkeypatch, tmp_path):
+    """Each ``liferaft.launch`` annotation of the shared plan names its
+    call's queries and distinct thresholds; summed, they are the
+    counters."""
+    from jax.profiler import ProfileData
+
+    obs = Observability()
+    eng, issued = _counted_shared_serve("shared", monkeypatch, obs,
+                                        profile_dir=tmp_path / "trace")
+    (path,) = (tmp_path / "trace").glob("**/*.xplane.pb")
+    launches = [
+        dict(ev.stats)
+        for plane in ProfileData.from_file(str(path)).planes
+        for line in plane.lines
+        for ev in line.events
+        if ev.name == "liferaft.launch"
+    ]
+    assert len(launches) == issued["calls"] > 0
+    assert sum(int(a["queries"]) for a in launches) == issued["queries"]
+    assert sum(int(a["classes"]) for a in launches) == issued["predicate_classes"]
+    assert all(1 <= int(a["classes"]) <= int(a["queries"]) <= 2 for a in launches)
