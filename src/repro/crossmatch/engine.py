@@ -83,6 +83,19 @@ class CrossMatchEngine:
             min_unit_bytes=self.cost_model.min_unit_bytes,
         )
         self.cache = BucketCache(cache_capacity)
+        # Bucket frames: a miss copies into a recycled frame instead of
+        # new arrays (``_read_miss``).  A frame is held while its bucket is
+        # resident; once evicted or read as a bypass it is released, and
+        # becomes free at the end of the round, since that round may still
+        # read it.  Frames held stay <= cache_capacity + the round's
+        # buckets.
+        self._frame_of: dict[int, dict[str, np.ndarray]] = {}
+        self._released: list[dict[str, np.ndarray]] = []
+        self._free_frames: list[dict[str, np.ndarray]] = []
+        self.frames_reused = 0  # misses copied into a recycled frame
+        self.frames_allocated = 0  # misses that allocated a new frame
+        self._m_frames = None
+        self.cache.subscribe(self._on_residency)
         self.cos_thr = float(np.cos(match_radius_rad))
         self.hybrid = hybrid
         self.use_pallas = use_pallas
@@ -127,6 +140,7 @@ class CrossMatchEngine:
             self.obs.attach_loop(
                 self.loop, track=0, clock="wall", h2d_bytes=cm_ops.h2d_bytes
             )
+            self._m_frames = self.obs.bucket_frame_counters()
 
     # -- loop-owned counters (kept as attributes for back-compat) --------------
     @property
@@ -190,6 +204,38 @@ class CrossMatchEngine:
         preds = np.array([self._pred_of(int(qid)) for qid in uniq], np.float64)
         return preds[inv, 0].astype(np.float32), preds[inv, 1]
 
+    # -- bucket frames -----------------------------------------------------------
+    def _on_residency(self, bucket_id) -> None:
+        """Cache listener: the frame of a bucket that left the cache
+        (eviction, refused insert, invalidation) is released."""
+        if not self.cache.contains(bucket_id):
+            frame = self._frame_of.pop(bucket_id, None)
+            if frame is not None:
+                self._released.append(frame)
+
+    def _read_miss(self, bucket_id: int):
+        """The 'disk read' of a miss, copied into a free frame (or a new
+        one while none is free).  Returns (frame, payload): the payload is
+        the frame itself, or its first rows for a short bucket."""
+        store = self.catalog.store
+        reused = bool(self._free_frames)
+        if reused:
+            frame = self._free_frames.pop()
+            self.frames_reused += 1
+        else:
+            frame = store.empty_frame()
+            self.frames_allocated += 1
+        if self._m_frames is not None:
+            self._m_frames[0 if reused else 1].inc()
+        n = store.spec(bucket_id).count
+        out = {k: a[:n] for k, a in frame.items()}  # views of the frame
+        return frame, store.read(bucket_id, out=out)
+
+    def _free_released(self) -> None:
+        """End of a round: no round reads the released frames any more."""
+        self._free_frames += self._released
+        self._released.clear()
+
     # -- per-bucket plumbing ---------------------------------------------------
     def _plan_and_fetch(self, decision: SchedulerDecision):
         """Hybrid plan + bucket payload with unified cache accounting:
@@ -215,12 +261,14 @@ class CrossMatchEngine:
             payload = self.cache.get(b)
             self.cache.access(b)  # counts the hit, refreshes LRU
         else:
-            payload = self.catalog.store.read(b)  # the 'disk read'
+            frame, payload = self._read_miss(b)  # the 'disk read'
             if plan is None or plan.strategy == "scan":
+                self._frame_of[b] = frame
                 self.cache.access(b, payload)
             else:
                 # Indexed cold read: no residency, but hit_rate must see
                 # the miss or skewed stats return (symmetric accounting).
+                self._released.append(frame)
                 self.cache.note_bypass_miss()
         cost = (
             plan.est_cost
@@ -306,11 +354,14 @@ class CrossMatchEngine:
         and the copy of the outputs back as host arrays), ``readback``
         (``np.asarray`` of those host arrays, about nothing) and
         ``route``."""
-        if self.shared_plan:
-            return self.execute_shared(decisions, vector)
-        if self._has_query_predicates:
-            return self._execute_per_predicate(decisions)
-        return self._execute_batched(decisions)
+        try:
+            if self.shared_plan:
+                return self.execute_shared(decisions, vector)
+            if self._has_query_predicates:
+                return self._execute_per_predicate(decisions)
+            return self._execute_batched(decisions)
+        finally:
+            self._free_released()
 
     def _execute_batched(self, decisions) -> float:
         """The historical homogeneous-predicate path: one device call per

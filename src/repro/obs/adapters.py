@@ -138,6 +138,20 @@ class Observability:
             h[2].inc(n - h[1])
             h[1] = n
 
+    def bucket_frame_counters(self) -> tuple:
+        """The (reused, allocated) children of
+        ``liferaft_bucket_frames_total``: bucket-cache misses by the frame
+        the engine copied them into (``CrossMatchEngine._read_miss``)."""
+        return tuple(
+            self.registry.counter(
+                "liferaft_bucket_frames_total",
+                "Bucket reads on the miss path, by whether the copy went "
+                "into a recycled frame or a newly allocated one",
+                outcome=outcome,
+            )
+            for outcome in ("reused", "allocated")
+        )
+
     def note_steal(self, ev) -> None:
         """``on_steal`` tap: one work-steal migration (reads ``ev`` only)."""
         m = self._steal_m

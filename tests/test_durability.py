@@ -564,11 +564,11 @@ class TestDrainFault:
         real_read = small_catalog.store.read
         calls = []
 
-        def failing_read(bucket_id):
+        def failing_read(bucket_id, out=None):
             calls.append(bucket_id)
             if len(calls) >= 2:
                 raise boom
-            return real_read(bucket_id)
+            return real_read(bucket_id, out=out)
 
         small_catalog.store.read = failing_read
         try:
@@ -584,7 +584,7 @@ class TestDrainFault:
     def test_error_chains_original_exception(self, small_catalog):
         sx = ShardedCrossMatch(small_catalog, n_shards=2, cache_capacity=4)
         real_read = small_catalog.store.read
-        small_catalog.store.read = lambda b: (_ for _ in ()).throw(
+        small_catalog.store.read = lambda b, out=None: (_ for _ in ()).throw(
             ValueError("disk on fire")
         )
         try:

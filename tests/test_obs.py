@@ -27,7 +27,9 @@ pin each clause of that contract:
   * shared-plan counters — calls, queries carried and distinct
     thresholds equal what ``execute_shared`` issued (0 on the fused
     path), and each shared ``launch`` span carries its call's queries
-    and classes.
+    and classes;
+  * bucket-frame counters — misses copied into a recycled frame or a new
+    one equal the engine's own counts.
 """
 import json
 import os
@@ -489,6 +491,35 @@ def test_h2d_counter_equals_the_operands_of_a_served_call():
     want = (_pow2(n) + _pow2(5)) * 8 * 4
     m = obs.snapshot()["metrics"]["liferaft_h2d_bytes_total"]["series"][0]
     assert m["value"] == want
+
+
+def test_bucket_frame_counters_equal_the_engine_counts():
+    """``liferaft_bucket_frames_total`` splits the misses by the frame they
+    were copied into; it equals the engine's plain counts, they sum to the
+    cache's misses, and a small cache recycles most frames."""
+    from repro.crossmatch import CrossMatchEngine, TraceConfig, make_trace
+
+    catalog = _catalog()
+    trace = make_trace(
+        catalog,
+        TraceConfig(n_queries=30, arrival_rate=2.0, objects_median=40, seed=19),
+    )
+    obs = Observability()
+    eng = CrossMatchEngine(
+        catalog, match_radius_rad=4e-3, cache_capacity=3, fuse_k=2, obs=obs
+    )
+    for q in trace:
+        eng.sim_clock = max(eng.sim_clock, q.arrival_time)
+        eng.submit(q)
+        eng.step()
+    eng.run([])
+    series = obs.snapshot()["metrics"]["liferaft_bucket_frames_total"]["series"]
+    got = {s["labels"]["outcome"]: s["value"] for s in series}
+    assert got == {
+        "reused": eng.frames_reused, "allocated": eng.frames_allocated,
+    }
+    assert eng.frames_reused + eng.frames_allocated == eng.cache.stats.misses
+    assert eng.frames_allocated <= 3 + 2 < eng.frames_reused
 
 
 def test_phase_spans_land_in_the_profiler_trace(tmp_path):
