@@ -8,9 +8,9 @@ Emits ``BENCH_scheduler.json`` with three measurements:
 2. ``decision_equivalence`` — both schedulers replay the same 500-query
    SkyQuery-style trace in lockstep; every decision (bucket id AND score)
    must be bit-identical (acceptance: 0 mismatches).
-3. ``compile_count`` — ``_crossmatch_jit`` shapes compiled while the
-   cross-match engine runs the 500-query trace with power-of-two shape
-   bucketing (acceptance: <= log2(max probe batch) + 1).
+3. ``compile_count`` — shapes of the one cross-match core
+   (``_crossmatch_shared_jit``) compiled while the cross-match engine
+   runs the 500-query trace with power-of-two shape bucketing (acceptance: <= log2(max probe batch) + 1).
 
 Run: ``PYTHONPATH=src python -m benchmarks.bench_scheduler [--out PATH]``
 """

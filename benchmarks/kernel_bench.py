@@ -30,7 +30,7 @@ def crossmatch_bench(verbose=True):
     hbm_us = (N * 8 + M * 8) * 4 / HW.hbm_bw * 1e6  # padded coords bf16-ish
     if verbose:
         print(f"  crossmatch 10k x 1k: cpu={us:.0f}us  v5e compute~{tpu_us:.2f}us "
-              f"hbm~{hbm_us:.2f}us (memory-bound: band-sparse tiles are the win)")
+              f"hbm~{hbm_us:.2f}us (memory-bound: skipped off-segment tiles are the win)")
     emit("kernel_crossmatch", us, f"v5e_est_us={max(tpu_us, hbm_us):.2f}")
 
 

@@ -18,7 +18,6 @@ def main() -> None:
         bench_prefetch,
         bench_scheduler,
         bench_shard,
-        bench_sharedplan,
         fig2_hybrid_join,
         fig5_bucket_reuse,
         fig6_workload_cdf,
@@ -39,7 +38,6 @@ def main() -> None:
         ("Scheduler hot path: incremental vs naive + compile counts", bench_scheduler.main),
         ("Adaptive control plane: closed loop vs best static alpha", bench_adaptive.main),
         ("Prefetch: scan-horizon staging vs reactive LRU", bench_prefetch.main),
-        ("Shared plans: masked multi-query kernel vs per-predicate", bench_sharedplan.main),
         ("Sharding: multi-shard tier + work stealing vs one loop", bench_shard.main),
         ("Observability: obs-on/off overhead + snapshot/Perfetto artifacts", bench_obs.main),
         ("Serving: multi-tenant LifeRaft engine", serving_bench.main),

@@ -10,10 +10,10 @@ from ``--seed``, and drives the trace through the served path,
 and ``pump``, in four phases:
 
 1. the default engine: the jnp join;
-2. ``use_pallas=True``: the single-bucket Pallas kernel;
-3. ``use_pallas=True, fuse_k=4``: the fused multi-bucket kernel;
+2. ``use_pallas=True``: the Pallas kernel, one bucket a call;
+3. ``use_pallas=True, fuse_k=4``: the Pallas kernel, four buckets a call;
 4. ``use_pallas=True, shared_plan=True`` with a per-query radius: the
-   shared-plan kernel with per-row thresholds.
+   Pallas kernel, ``share_width`` queries a call with per-row thresholds.
 
 Phases 2-4 take the first 60 queries.  Every phase is checked against a
 float64 brute-force join (:func:`reference_join`) that shares no code with
@@ -291,18 +291,9 @@ def holds_mosaic(engine_kw) -> bool:
     interpret = cm_ops._resolve_interpret(None, use_pallas)
     x = np.zeros((8, 8), np.float32)
     v = np.zeros(8, np.float32)
-    if engine_kw.get("shared_plan"):
-        lowered = cm_ops._crossmatch_shared_jit.lower(
-            x, x, v, v, v, use_pallas, 128, 512, interpret
-        )
-    elif engine_kw.get("fuse_k", 1) > 1:
-        lowered = cm_ops._crossmatch_fused_jit.lower(
-            x, x, v, v, 0.5, use_pallas, 128, 512, interpret
-        )
-    else:
-        lowered = cm_ops._crossmatch_jit.lower(
-            x, x, 0.5, use_pallas, 128, 512, None, interpret
-        )
+    lowered = cm_ops._crossmatch_shared_jit.lower(
+        x, x, v, v, v, use_pallas, 128, 512, interpret
+    )
     return "tpu_custom_call" in lowered.compile().as_text()
 
 

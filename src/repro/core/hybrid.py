@@ -34,10 +34,6 @@ class HybridCostModel(CostModel):
     """
 
     T_probe: float = 4.13e-3
-    # Fixed per-device-call overhead (kernel launch + host sync).  Zero by
-    # default so single-bucket plans are unchanged; a shared plan amortizes
-    # it across every scan member of the group (the third break-even axis).
-    T_dispatch: float = 0.0
 
     def indexed_cost(self, queue_size: int) -> float:
         return self.T_probe * queue_size
@@ -90,50 +86,3 @@ class HybridPlanner:
             queue_size=queue_size,
             in_cache=in_cache,
         )
-
-    def plan_group(
-        self, members: list[tuple[int, bool]]
-    ) -> list[JoinPlan]:
-        """Shared-plan break-even: plan a whole fuse group at once.
-
-        ``members`` is [(queue_size, in_cache), ...] for the buckets a
-        shared device call would cover.  Scan members split ONE kernel
-        launch, so each one's scan cost carries only ``T_dispatch / s``
-        (s = number of scan members) while an indexed member pays the full
-        ``T_dispatch`` for its private probe call — batching the query
-        axis moves the scan-vs-indexed break-even toward scan as the group
-        grows.  This is the plan's third axis: queue size, cache
-        residency, and now group size.  With ``T_dispatch == 0`` (the
-        default cost model) every decision matches per-member ``plan()``.
-
-        Fixed point in one descending pass: members are ranked by how much
-        scan beats indexed; a member joins the scan set only if it still
-        prefers scan with the launch overhead split s ways *including
-        itself*, and each join only further cheapens scan for the rest.
-        """
-        overhead = getattr(self.cost, "T_dispatch", 0.0)
-        base = [
-            (self.cost.scan_cost(qs, ic), self.cost.indexed_cost(qs), qs, ic)
-            for qs, ic in members
-        ]
-        if overhead <= 0.0:
-            return [self.plan(qs, ic) for qs, ic in members]
-        order = sorted(range(len(base)), key=lambda i: base[i][0] - base[i][1])
-        plans: list[JoinPlan | None] = [None] * len(base)
-        scan_set: list[int] = []
-        for i in order:
-            scan, idx, qs, ic = base[i]
-            s = len(scan_set) + 1
-            if self.threshold_frac is not None:
-                use_scan = qs >= self.threshold_frac * self.objects_per_bucket
-            else:
-                use_scan = scan + overhead / s <= idx + overhead
-            if use_scan:
-                scan_set.append(i)
-        s = max(len(scan_set), 1)
-        for i, (scan, idx, qs, ic) in enumerate(base):
-            if i in scan_set:
-                plans[i] = JoinPlan("scan", scan + overhead / s, qs, ic)
-            else:
-                plans[i] = JoinPlan("indexed", idx + overhead, qs, ic)
-        return plans

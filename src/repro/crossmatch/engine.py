@@ -9,14 +9,15 @@ This is the paper's Fig. 3 wired together:
   Bucket Cache         -> LRU over bucket payloads
 
 The join itself runs as real JAX compute (``repro.kernels.crossmatch``):
-probe objects of *every* pending query for the chosen bucket are batched
-into one device call — the paper's single shared pass.  With
-``fuse_k > 1`` the engine goes one step further: the top-k buckets by U_a
-are evaluated in ONE segment-masked device call (``crossmatch_fused``),
-amortizing dispatch across buckets the way the paper amortizes disk reads
-across queries.  Probe batches are shape-bucketed to powers of two inside
-the kernel wrappers, so a long trace compiles O(log max_batch) kernel
-variants instead of one per distinct batch size.
+probe objects of *every* pending query for the chosen buckets are batched
+into one segment-masked device call (``crossmatch_shared``) — the paper's
+single shared pass.  With ``fuse_k > 1`` the top-k buckets by U_a share
+that call, amortizing dispatch across buckets the way the paper amortizes
+disk reads across queries, and each probe row carries its own query's
+threshold, so heterogeneous predicates share it too.  Probe batches are
+shape-bucketed to powers of two inside the kernel wrappers, so a long
+trace compiles O(log max_batch) kernel variants instead of one per
+distinct batch size.
 
 Per-query predicates (here: magnitude cuts) are applied on the matched
 tuples before results are routed back to their parent queries.
@@ -55,6 +56,26 @@ class MatchResult:
 
 
 class CrossMatchEngine:
+    """Single-shard cross-match engine over one catalog.
+
+    Every round joins through one call pattern of one kernel
+    (``execute_shared``): the round's buckets (one, or the top ``fuse_k``)
+    concatenated with segment ids, every pending query's probe rows with
+    their own threshold and magnitude cut (``meta['radius']`` /
+    ``meta['mag_cut']``, else ``match_radius_rad`` / ``mag_cut``).
+    ``shared_plan`` only chooses how a round's queries are chunked into
+    device calls:
+
+    * ``shared_plan=False``: every query of the round in one call, so a
+      round is one device call;
+    * ``shared_plan=True``: ``share_width`` queries a call (or the control
+      vector's ``share_width`` when its law is on), the bound that keeps
+      each call's probe batch, and so its compiled shapes, small.
+
+    ``hybrid`` decides each bucket read's residency and cost, never the
+    compute.
+    """
+
     def __init__(
         self,
         catalog: SkyCatalog,
@@ -101,14 +122,10 @@ class CrossMatchEngine:
         self.use_pallas = use_pallas
         self.mag_cut = mag_cut
         self.fuse_k = max(1, int(fuse_k))
-        # Shared query plans: evaluate the whole query batch's predicates
-        # in ONE masked device call (per share_width-sized chunk) instead
-        # of one dispatch per predicate class.  Off by default; the
-        # per-query predicate surface is meta['radius'] / meta['mag_cut'].
+        # Chunking of a round's queries into device calls (class docstring).
         self.shared_plan = bool(shared_plan)
         self.share_width = max(1, int(share_width))
         self._pred_cache: dict[int, tuple[float, float]] = {}
-        self._has_query_predicates = False
         self.results: dict[int, list[MatchResult]] = {}
         self.max_probe_batch = 0  # largest probe batch sent to the device
         # The shared scheduling inner loop; the controller (when given) is
@@ -173,9 +190,6 @@ class CrossMatchEngine:
     def _note_submitted(self, query: Query) -> None:
         self.loop.observe_arrival(query.arrival_time)
         self.results.setdefault(query.query_id, [])
-        meta = query.meta or {}
-        if "radius" in meta or "mag_cut" in meta:
-            self._has_query_predicates = True
 
     # -- per-query predicates -----------------------------------------------------
     def _pred_of(self, query_id: int) -> tuple[float, float]:
@@ -238,10 +252,11 @@ class CrossMatchEngine:
 
     # -- per-bucket plumbing ---------------------------------------------------
     def _plan_and_fetch(self, decision: SchedulerDecision):
-        """Hybrid plan + bucket payload with unified cache accounting:
-        every resident read records a hit via ``cache.access`` (the indexed
-        plan used to read through ``cache.get`` and skew the hit-rate);
-        only scan plans establish residency on a miss.
+        """(payload, cost) of one bucket read under the hybrid plan, with
+        unified cache accounting: every resident read records a hit via
+        ``cache.access`` (the indexed plan used to read through
+        ``cache.get`` and skew the hit-rate); only scan plans establish
+        residency on a miss.
 
         Residency is re-probed here rather than taken from the decision:
         within a fused dispatch an earlier bucket's insertion can evict a
@@ -279,7 +294,7 @@ class CrossMatchEngine:
         )
         if ph is not None:
             ph.end()
-        return plan, payload, cost
+        return payload, cost
 
     def _gather_probes(self, bucket_id: int):
         q = self.wm.queue(bucket_id)
@@ -307,17 +322,16 @@ class CrossMatchEngine:
 
     def _route(
         self, bucket_id, units, owners, probe_local, best_idx, best_dot, n_cand,
-        payload, mag_cut_row=None,
+        payload, mag_cut_row,
     ) -> None:
         matched = n_cand > 0
         # Per-query predicate on the joined tuples (paper: "query specific
         # predicates are applied on the output tuples that succeed").
-        # ``mag_cut_row`` carries each row's owning query's own cut when
-        # queries have heterogeneous predicates.
+        # ``mag_cut_row`` carries each row's owning query's own cut.
         mags = np.asarray(payload["mags"])[
             np.clip(best_idx, 0, len(payload["mags"]) - 1)
         ]
-        matched &= mags <= (self.mag_cut if mag_cut_row is None else mag_cut_row)
+        matched &= mags <= mag_cut_row
         global_rows = self.catalog.partitioner.object_slice(bucket_id)
         for u in units:
             sel = (owners == u.query_id) & matched
@@ -341,182 +355,34 @@ class CrossMatchEngine:
         return None if outcome is None else outcome.decisions[0].bucket_id
 
     def _execute(self, decisions, vector) -> float:
-        """DispatchLoop executor: route the round to the shared-plan path,
-        the per-predicate-class path (heterogeneous predicates without a
-        shared plan), or the historical batched/fused path.  Returns the
-        round's cost on the cost model's clock (T_b per bucket read, T_m
-        per probe), not its wall time.
-
-        With ``loop.phases`` set, every path records the same phase spans:
-        ``fetch`` per bucket, ``gather`` (probe rows, f32 casts, segment
-        and operand concatenation), ``launch`` (the ``ops.crossmatch*``
-        call: padding, transfer, dispatch, any compile, the device's work
-        and the copy of the outputs back as host arrays), ``readback``
-        (``np.asarray`` of those host arrays, about nothing) and
-        ``route``."""
+        """DispatchLoop executor: the one join path, ``execute_shared``.
+        Returns the round's cost on the cost model's clock (T_b per bucket
+        read, T_m per probe), not its wall time."""
         try:
-            if self.shared_plan:
-                return self.execute_shared(decisions, vector)
-            if self._has_query_predicates:
-                return self._execute_per_predicate(decisions)
-            return self._execute_batched(decisions)
+            return self.execute_shared(decisions, vector)
         finally:
             self._free_released()
 
-    def _execute_batched(self, decisions) -> float:
-        """The historical homogeneous-predicate path: one device call per
-        round (single bucket, or the fuse_k segment-masked fused call)."""
-        from ..kernels.crossmatch import ops as cm_ops
-
-        ph = self.loop.phases
-        total_cost = 0.0
-        if len(decisions) == 1:
-            decision = decisions[0]
-            b = decision.bucket_id
-            _, payload, cost = self._plan_and_fetch(decision)
-            total_cost += cost
-            if ph is not None:
-                ph.phase("gather")
-            units, probe_pos, owners, probe_local = self._gather_probes(b)
-            self.max_probe_batch = max(self.max_probe_batch, len(probe_pos))
-            bucket32 = np.asarray(payload["positions"], dtype=np.float32)
-            probes32 = probe_pos.astype(np.float32)
-            if ph is not None:
-                ph.phase("launch")
-            # --- the shared pass: one batched device call for every query ---
-            out = cm_ops.crossmatch(
-                bucket32, probes32, self.cos_thr, use_pallas=self.use_pallas
-            )
-            if ph is not None:
-                ph.phase("readback")
-            best_idx, best_dot, n_cand = (np.asarray(a) for a in out)
-            if ph is not None:
-                ph.phase("route")
-            self._route(
-                b, units, owners, probe_local, best_idx, best_dot, n_cand,
-                payload,
-            )
-        else:
-            # --- fused multi-bucket pass: top-k buckets, ONE device call ---
-            per_bucket = []
-            bucket_parts, probe_parts, bseg, pseg = [], [], [], []
-            row_off = 0
-            for s, decision in enumerate(decisions):
-                b = decision.bucket_id
-                _, payload, cost = self._plan_and_fetch(decision)
-                total_cost += cost
-                if ph is not None:
-                    ph.phase("gather")
-                units, probe_pos, owners, probe_local = self._gather_probes(b)
-                pos = np.asarray(payload["positions"], dtype=np.float32)
-                bucket_parts.append(pos)
-                probe_parts.append(probe_pos.astype(np.float32))
-                bseg.append(np.full(len(pos), s, np.int32))
-                pseg.append(np.full(len(probe_pos), s, np.int32))
-                per_bucket.append(
-                    (b, payload, units, owners, probe_local, row_off,
-                     len(probe_pos))
-                )
-                row_off += len(pos)
-            self.max_probe_batch = max(
-                self.max_probe_batch, sum(len(p) for p in probe_parts)
-            )
-            operands = (
-                np.concatenate(bucket_parts),
-                np.concatenate(probe_parts),
-                np.concatenate(bseg),
-                np.concatenate(pseg),
-            )
-            if ph is not None:
-                ph.phase("launch")
-            out = cm_ops.crossmatch_fused(
-                *operands, self.cos_thr, use_pallas=self.use_pallas
-            )
-            if ph is not None:
-                ph.phase("readback")
-            best_idx, best_dot, n_cand = (np.asarray(a) for a in out)
-            if ph is not None:
-                ph.phase("route")
-            p_off = 0
-            for b, payload, units, owners, probe_local, row_off, n_p in per_bucket:
-                sl = slice(p_off, p_off + n_p)
-                p_off += n_p
-                local_idx = np.clip(
-                    best_idx[sl] - row_off, 0, len(payload["mags"]) - 1
-                )
-                self._route(
-                    b, units, owners, probe_local,
-                    local_idx, best_dot[sl], n_cand[sl], payload,
-                )
-        if ph is not None:
-            ph.end()
-        return total_cost
-
-    def _execute_per_predicate(self, decisions) -> float:
-        """Per-predicate-class baseline: queries carry their own radii /
-        mag cuts, so the static-``cos_thr`` kernel needs one device call
-        per (bucket, distinct threshold) pair — the dispatch storm the
-        shared plan collapses.  Kept as the off-path so ``shared_plan``
-        stays a pure performance switch with bit-equal results."""
-        from ..kernels.crossmatch import ops as cm_ops
-
-        ph = self.loop.phases
-        total_cost = 0.0
-        n_calls = 0
-        for decision in decisions:
-            b = decision.bucket_id
-            _, payload, cost = self._plan_and_fetch(decision)
-            total_cost += cost
-            if ph is not None:
-                ph.phase("gather")
-            units, probe_pos, owners, probe_local = self._gather_probes(b)
-            self.max_probe_batch = max(self.max_probe_batch, len(probe_pos))
-            pos = np.asarray(payload["positions"], dtype=np.float32)
-            probes32 = probe_pos.astype(np.float32)
-            thr_row, mag_row = self._pred_rows(owners)
-            best_idx = np.zeros(len(owners), np.int64)
-            best_dot = np.zeros(len(owners), np.float32)
-            n_cand = np.zeros(len(owners), np.int64)
-            for thr in np.unique(thr_row):
-                if ph is not None:
-                    ph.phase("gather")
-                sel = thr_row == thr
-                probes_sel = probes32[sel]
-                if ph is not None:
-                    ph.phase("launch")
-                out = cm_ops.crossmatch(
-                    pos, probes_sel, float(thr), use_pallas=self.use_pallas
-                )
-                if ph is not None:
-                    ph.phase("readback")
-                best_idx[sel], best_dot[sel], n_cand[sel] = (
-                    np.asarray(a) for a in out
-                )
-                n_calls += 1
-            if ph is not None:
-                ph.phase("route")
-            self._route(
-                b, units, owners, probe_local, best_idx, best_dot, n_cand,
-                payload, mag_cut_row=mag_row,
-            )
-        if ph is not None:
-            ph.end()
-        self.loop.note_device_dispatches(n_calls)
-        return total_cost
-
     def execute_shared(self, bucket_group, vector=None) -> float:
-        """Shared-plan executor: ONE masked device call (per share_width
-        chunk) for the whole bucket group x query batch.
+        """Join a round's buckets against every pending query's probes.
 
         ``bucket_group`` is the round's SchedulerDecisions (bare bucket ids
-        are accepted and looked up).  All pending queries' predicates are
-        gathered into per-probe-row threshold/mag-cut vectors and the join
-        runs through ``crossmatch_shared`` — the (queries x objects) mask —
-        so k buckets and Q predicate classes cost ceil(Q / share_width)
-        dispatches instead of k*Q.  The hybrid planner's group plan is the
-        third break-even axis: members it sends down the indexed path keep
-        private per-predicate calls (tiny batches don't pay the shared
-        scan), the scan members share the masked kernel.
+        are accepted and looked up).  Each bucket is fetched through
+        ``_plan_and_fetch`` (the hybrid plan decides residency and cost);
+        the buckets' payloads and probe rows are concatenated with segment
+        ids, the queries' predicates gathered into per-probe-row
+        threshold/mag-cut vectors, and the join runs through
+        ``crossmatch_shared`` — the (queries x objects) mask — once per
+        chunk of the round's queries (see the constructor), so k buckets
+        and Q predicate classes cost one call per chunk instead of k*Q.
+
+        With ``loop.phases`` set, the round records its phase spans:
+        ``fetch`` per bucket, ``gather`` (probe rows, f32 casts, segment
+        and operand concatenation), ``launch`` per call (the
+        ``ops.crossmatch_shared`` call: padding, transfer, dispatch, any
+        compile, the device's work and the copy of the outputs back as
+        host arrays), ``readback`` (``np.asarray`` of those host arrays,
+        about nothing) and ``route``.
         """
         from ..kernels.crossmatch import ops as cm_ops
 
@@ -531,41 +397,14 @@ class CrossMatchEngine:
             )
             for d in bucket_group
         ]
-        width = getattr(vector, "share_width", 0) or self.share_width
         ph = self.loop.phases
         total_cost = 0.0
-        n_calls = 0
-
-        # Group plan (third axis): members that still prefer indexed
-        # probes peel off to their own calls; the rest share one plan.
-        if self.hybrid is not None and hasattr(self.hybrid, "plan_group"):
-            plans = self.hybrid.plan_group(
-                [
-                    (d.queue_size, self.cache.contains(d.bucket_id))
-                    for d in decisions
-                ]
-            )
-        else:
-            plans = [None] * len(decisions)
-
-        shared, indexed = [], []
-        for decision, plan in zip(decisions, plans):
-            if plan is not None and plan.strategy == "indexed":
-                indexed.append(decision)
-            else:
-                shared.append(decision)
-        if indexed:
-            total_cost += self._execute_per_predicate(indexed)
-
-        if not shared:
-            return total_cost
-
         per_bucket = []
         bucket_parts, probe_parts, bseg, pseg = [], [], [], []
         row_off = 0
-        for s, decision in enumerate(shared):
+        for s, decision in enumerate(decisions):
             b = decision.bucket_id
-            _, payload, cost = self._plan_and_fetch(decision)
+            payload, cost = self._plan_and_fetch(decision)
             total_cost += cost
             if ph is not None:
                 ph.phase("gather")
@@ -588,16 +427,20 @@ class CrossMatchEngine:
         self.max_probe_batch = max(self.max_probe_batch, len(probes_cat))
         thr_row, mag_row = self._pred_rows(owners_cat)
 
-        # Chunk the query batch by share_width (the AIMD-bounded compile
-        # ceiling): each chunk's probe rows go through one shared call
-        # against the same concatenated bucket payload, and outputs are
-        # scattered back into full-length arrays so routing below is
-        # order-identical to the fused path.
+        # Chunk the round's queries: each chunk's probe rows go through
+        # one call against the same concatenated bucket payload, and
+        # outputs are scattered back into full-length arrays so routing
+        # below is in bucket order whatever the chunking.
         qids = list(dict.fromkeys(owners_cat.tolist()))  # first-appearance
+        if self.shared_plan:
+            width = getattr(vector, "share_width", 0) or self.share_width
+        else:
+            width = max(len(qids), 1)
         best_idx = np.zeros(len(owners_cat), np.int64)
         best_dot = np.zeros(len(owners_cat), np.float32)
         n_cand = np.zeros(len(owners_cat), np.int64)
         chunks = [qids[i : i + width] for i in range(0, len(qids), width)] or [[]]
+        n_calls = 0
         n_classes = 0  # distinct thresholds of each call, summed
         for chunk in chunks:
             if ph is not None:
@@ -622,9 +465,7 @@ class CrossMatchEngine:
             )
             n_calls += 1
             n_classes += classes
-        occupancy = (
-            len(qids) / (len(chunks) * width) if qids and chunks else 0.0
-        )
+        occupancy = len(qids) / (len(chunks) * width) if qids else 0.0
         self.loop.note_device_dispatches(
             n_calls, shared_occupancy=occupancy,
             queries=len(qids), classes=n_classes,
@@ -641,8 +482,7 @@ class CrossMatchEngine:
             )
             self._route(
                 b, units, owners, probe_local,
-                local_idx, best_dot[sl], n_cand[sl], payload,
-                mag_cut_row=mag_row[sl],
+                local_idx, best_dot[sl], n_cand[sl], payload, mag_row[sl],
             )
         if ph is not None:
             ph.end()
@@ -872,9 +712,6 @@ class ShardedCrossMatch:
                 )
                 for q in qmap.values():
                     thief.results.setdefault(q.query_id, [])
-                    meta = q.meta or {}
-                    if "radius" in meta or "mag_cut" in meta:
-                        thief._has_query_predicates = True
                 self.steals.append(
                     StealEvent(
                         bucket_id=bucket_id,

@@ -15,7 +15,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-__all__ = ["crossmatch_ref", "crossmatch_fused_ref", "crossmatch_shared_ref"]
+__all__ = ["crossmatch_ref", "crossmatch_shared_ref"]
 
 
 def _dots(probes: jnp.ndarray, bucket: jnp.ndarray) -> jnp.ndarray:
@@ -32,25 +32,6 @@ def crossmatch_ref(bucket: jnp.ndarray, probes: jnp.ndarray, cos_thr: float):
     return best_idx, best_dot, n_cand
 
 
-def crossmatch_fused_ref(
-    bucket: jnp.ndarray,
-    probes: jnp.ndarray,
-    bucket_seg: jnp.ndarray,
-    probe_seg: jnp.ndarray,
-    cos_thr: float,
-):
-    """Segmented oracle: probe m only considers bucket rows with
-    ``bucket_seg == probe_seg[m]``; other pairs get dot -2 (below any real
-    dot and any threshold).  ``best_idx`` indexes the concatenated bucket."""
-    dots = _dots(probes, bucket)
-    same = probe_seg[:, None] == bucket_seg[None, :]
-    dots = jnp.where(same, dots, jnp.float32(-2.0))
-    best_idx = jnp.argmax(dots, axis=1).astype(jnp.int32)
-    best_dot = jnp.max(dots, axis=1)
-    n_cand = jnp.sum(dots >= cos_thr, axis=1).astype(jnp.int32)
-    return best_idx, best_dot, n_cand
-
-
 def crossmatch_shared_ref(
     bucket: jnp.ndarray,
     probes: jnp.ndarray,
@@ -58,8 +39,11 @@ def crossmatch_shared_ref(
     probe_seg: jnp.ndarray,
     probe_thr: jnp.ndarray,
 ):
-    """Shared-plan oracle: the fused segment mask *plus* a per-probe-row
-    threshold vector, realizing the (queries x objects) predicate mask.
+    """Segmented oracle: probe m only considers bucket rows with
+    ``bucket_seg == probe_seg[m]`` (other pairs get dot -2, below any real
+    dot and any threshold), and counts against its own per-row threshold,
+    realizing the (queries x objects) predicate mask.  ``best_idx``
+    indexes the concatenated bucket.
 
     Each probe row belongs to one query; ``probe_thr[m]`` is that query's
     own cos(match radius), so heterogeneous per-query predicates evaluate

@@ -3,10 +3,11 @@
 Queries that each carry their own radius and magnitude cut, the traffic's
 25 predicate classes, are served through ``ServiceDaemon`` by the engine
 the benchmark builds for the cell, at a small size with the Pallas kernels
-interpreted, and checked against the benchmark's float64 reference.  The
-shared plan and the per-predicate path must both pass and give the same
-bits.  The cell itself must load by name, give every block of its stream
-each class once, and reach only the shared kernel core.
+interpreted, and checked against the benchmark's float64 reference.  With
+``shared_plan`` on (8 queries a call) and off (a round's queries in one
+call) the engine must pass and give the same bits.  The cell itself must
+load by name, give every block of its stream each class once, and reach
+only the shared kernel core.
 """
 from __future__ import annotations
 
@@ -103,11 +104,11 @@ def test_served_predicates_match_the_reference(shared_plan):
     cfg, catalog, layout, queries, engine = _serve(shared_plan)
     assert len({(q.meta["radius"], q.meta["mag_cut"]) for q in queries}) == 25
     assert {q.query_id for q in queries} <= set(engine.wm.completed)
-    if shared_plan:
-        assert engine.loop.shared_calls > 0
-        assert engine.loop.shared_classes > engine.loop.shared_calls
-    else:
-        assert engine.loop.shared_calls == 0
+    loop = engine.loop
+    assert loop.shared_calls == loop.device_dispatches > 0
+    assert loop.shared_classes > loop.shared_calls
+    if not shared_plan:
+        assert loop.device_dispatches == loop.dispatches
     ref = reference.reference_join(catalog, layout, queries, harness.pred_of(cfg))
     rep = reference.check_results(layout, engine.results, ref)
     assert rep["wrong"] == 0, rep

@@ -55,25 +55,6 @@ class TestCrossmatch:
         assert (np.asarray(c) >= 1).all()
         np.testing.assert_allclose(np.asarray(d), 1.0, atol=1e-5)
 
-    def test_banded_near_diagonal(self):
-        """With SFC-sorted identical sets, a moderate band keeps all matches."""
-        from repro.core.sfc import htm_id
-
-        pts = _unit(1024, 6)
-        order = np.argsort(htm_id(pts, level=8), kind="stable")
-        pts = pts[order]
-        thr = float(np.cos(0.01))
-        fi, fd, fc = cm_ops.crossmatch(
-            pts, pts, thr, use_pallas=True, bm=128, bn=128, interpret=True
-        )
-        bi, bd, bc = cm_ops.crossmatch(
-            pts, pts, thr, use_pallas=True, bm=128, bn=128, band=0, interpret=True
-        )
-        # band=0 keeps only the diagonal tile: self-match must survive
-        np.testing.assert_allclose(np.asarray(bd), 1.0, atol=1e-5)
-        assert (np.asarray(bc) >= 1).all()
-        assert (np.asarray(bc) <= np.asarray(fc)).all()
-
     @given(st.integers(1, 400), st.integers(1, 400))
     @settings(max_examples=10, deadline=None)
     def test_property_any_shape(self, n, m):
@@ -87,8 +68,9 @@ class TestCrossmatch:
     @pytest.mark.parametrize("use_pallas", [False, True])
     def test_padded_rows_not_counted_at_large_radius(self, radius, use_pallas):
         """Regression: cos_thr <= 0 used to count every zero-padded bucket
-        row (dot 0 >= cos_thr) in n_cand.  The marker-column sentinel pins
-        padded-row dots at -2, below any threshold."""
+        row (dot 0 >= cos_thr) in n_cand.  The segment mask pins padded-row
+        dots at -2 (their segment ``PAD_SEG`` matches no probe's), below
+        any threshold."""
         bkt, prb = _unit(700, 7), _unit(300, 8)  # 700 % bn != 0 forces padding
         thr = float(np.cos(radius))
         assert thr <= 0.0
@@ -101,7 +83,7 @@ class TestCrossmatch:
 
     def test_shape_bucketing_bounds_compiles(self):
         """Sweeping probe counts must reuse O(log M) compiled shapes.
-        ``jit_cache_size`` counts the cores' entries only; the wrappers'
+        ``jit_cache_size`` counts the core's entries only; the wrappers'
         own compiles are counted in ``test_new_probe_count_compiles_nothing``."""
         bkt = _unit(500, 11)
         thr = float(np.cos(0.05))
@@ -160,6 +142,32 @@ class TestCrossmatchFused:
         )
         assert (np.asarray(c) == 0).all()
         assert (np.asarray(d) <= -1.5).all()  # masked sentinel, never a match
+
+
+@pytest.mark.parametrize("use_pallas", [False, True])
+def test_three_wrappers_share_one_core_entry(use_pallas):
+    """``crossmatch``, ``crossmatch_fused`` and ``crossmatch_shared`` at one
+    pow2 shape pair add one ``jit_cache_size()`` entry between them, and
+    give bit-equal outputs under a uniform threshold."""
+    # (3000, 40) pads to (4096, 64), which no other test compiles with
+    # bn=256.
+    bkt, prb = _unit(3000, 31), _unit(40, 32)
+    thr = float(np.cos(0.05))
+    bseg, pseg = np.zeros(3000, np.int32), np.zeros(40, np.int32)
+    kw = dict(use_pallas=use_pallas, bn=256, interpret=True)
+    before = cm_ops.jit_cache_size()
+    outs = [
+        cm_ops.crossmatch(bkt, prb, thr, **kw),
+        cm_ops.crossmatch_fused(bkt, prb, bseg, pseg, thr, **kw),
+        cm_ops.crossmatch_shared(
+            bkt, prb, bseg, pseg, np.full(40, thr, np.float32), **kw
+        ),
+    ]
+    assert cm_ops.jit_cache_size() - before == 1
+    for out in outs[1:]:
+        for a, b in zip(outs[0], out):
+            assert a.dtype == b.dtype and a.tobytes() == b.tobytes()
+    assert (outs[0][2] > 0).any()  # the threshold passes some pairs
 
 
 @pytest.mark.parametrize("wrapper", ["crossmatch", "crossmatch_fused",

@@ -18,15 +18,15 @@ pin each clause of that contract:
     verdict counters, metrics_text/metrics_snapshot, and their empty
     obs-off fallbacks;
   * ControlExplain — vector changes carry the trigger-signal reason;
-  * wall-clock phase spans — a served cross-match round, on each executor
-    path, is one span whose children are exactly its measured phases,
+  * wall-clock phase spans — a served cross-match round, under each
+    engine setting, is one span whose children are exactly its measured phases,
     inside the round and not overlapping; a submit is one span with a
     decompose child; both land in the profiler's trace as
     ``liferaft.*`` annotations; the host-to-device byte counter equals
     the padded operands' bytes;
   * shared-plan counters — calls, queries carried and distinct
-    thresholds equal what ``execute_shared`` issued (0 on the fused
-    path), and each shared ``launch`` span carries its call's queries
+    thresholds equal what ``execute_shared`` issued (every call of the
+    one join path), and each ``launch`` span carries its call's queries
     and classes;
   * bucket-frame counters — misses copied into a recycled frame or a new
     one equal the engine's own counts.
@@ -308,7 +308,8 @@ def test_control_explain_names_the_trigger_signal():
 ROUND_PHASES = {
     "select", "fetch", "gather", "launch", "readback", "route", "complete",
 }
-# Executor path -> CrossMatchEngine settings that take it.
+# Call pattern -> CrossMatchEngine settings that take it: one bucket,
+# three buckets, per-query predicates in one call, in chunks of two.
 PATHS = {
     "single": dict(fuse_k=1),
     "fused": dict(fuse_k=3),
@@ -445,9 +446,9 @@ def _pow2(n):
 
 @pytest.mark.parametrize("core", ["single", "fused", "shared"])
 def test_h2d_bytes_of_one_known_call(core):
-    """``ops.h2d_bytes`` grows by the nbytes of the padded operands the
-    wrapper hands to its jitted core: (rows, 8) f32 coordinates, and f32
-    segment ids and thresholds, each padded to a power of two."""
+    """``ops.h2d_bytes`` grows by the nbytes of the padded operands every
+    wrapper hands to the one jitted core: (rows, 8) f32 coordinates, and
+    f32 segment ids and thresholds, each padded to a power of two."""
     from repro.kernels.crossmatch import ops
 
     n, m = 37, 5
@@ -458,21 +459,20 @@ def test_h2d_bytes_of_one_known_call(core):
     before = ops.h2d_bytes()
     if core == "single":
         ops.crossmatch(bucket, probes, 0.5)
-        want = (_pow2(n) + _pow2(m)) * 8 * 4
     elif core == "fused":
         ops.crossmatch_fused(bucket, probes, bseg, pseg, 0.5)
-        want = (_pow2(n) + _pow2(m)) * 9 * 4
     else:
         ops.crossmatch_shared(bucket, probes, bseg, pseg,
                               np.full(m, 0.5, np.float32))
-        want = (_pow2(n) * 9 + _pow2(m) * 10) * 4
+    want = (_pow2(n) * 9 + _pow2(m) * 10) * 4
     assert ops.h2d_bytes() - before == want
 
 
 def test_h2d_counter_equals_the_operands_of_a_served_call():
     """One query of five objects inside one bucket: one round, one
     single-bucket call, and ``liferaft_h2d_bytes_total`` holds exactly
-    the bytes of its two padded operands."""
+    the bytes of its padded operands: coordinates, segment ids and
+    thresholds."""
     from repro.core.workload import Query
     from repro.crossmatch import CrossMatchEngine
 
@@ -488,7 +488,7 @@ def test_h2d_counter_equals_the_operands_of_a_served_call():
     eng.run([q])
     assert eng.loop.dispatches == 1
     n = len(payload["positions"])
-    want = (_pow2(n) + _pow2(5)) * 8 * 4
+    want = (_pow2(n) * 9 + _pow2(5) * 10) * 4
     m = obs.snapshot()["metrics"]["liferaft_h2d_bytes_total"]["series"][0]
     assert m["value"] == want
 
@@ -645,8 +645,8 @@ def test_shared_plan_counters_equal_what_execute_shared_issued(path, monkeypatch
         assert issued["calls"] <= issued["queries"] <= 2 * issued["calls"]
         assert loop.device_dispatches == issued["calls"]
     else:
-        assert issued == dict.fromkeys(SHARED_COUNTERS, 0)
-        assert loop.device_dispatches == loop.dispatches > 0
+        # Shared plan off: every query of a round rides in its one call.
+        assert issued["calls"] == loop.device_dispatches == loop.dispatches > 0
     text = obs.prometheus()
     for c in SHARED_COUNTERS:
         assert f"liferaft_shared_{c}_total" in text

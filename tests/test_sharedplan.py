@@ -9,10 +9,13 @@ Three layers under test:
 * compile bounding — K distinct predicates in one shared call must cost
   at most one ``jit_cache_size`` entry per pow2 shape pair, not K;
 * control + engine — the AIMD ``share_width`` law, and the cross-match
-  engine's ``execute_shared`` producing results bit-equal to the
-  per-predicate off path while issuing strictly fewer device dispatches.
+  engine's one join path giving bit-equal results whatever its chunking:
+  one call a round with ``shared_plan`` off, one per ``share_width``
+  queries with it on.
 """
 from __future__ import annotations
+
+import dataclasses
 
 import numpy as np
 import pytest
@@ -228,7 +231,7 @@ class TestShareWidthLaw:
 
 
 class TestEngineSharedPlan:
-    def _setup(self, **eng_kw):
+    def _setup(self, fuse_k=3, **eng_kw):
         from repro.crossmatch import (
             CrossMatchEngine, TraceConfig, make_catalog, make_trace,
         )
@@ -246,7 +249,7 @@ class TestEngineSharedPlan:
             q.meta["radius"] = float(rng.choice([2e-3, 4e-3, 8e-3]))
             q.meta["mag_cut"] = float(rng.choice([23.0, 24.0, 25.0]))
         eng = CrossMatchEngine(
-            catalog, match_radius_rad=4e-3, fuse_k=3, **eng_kw
+            catalog, match_radius_rad=4e-3, fuse_k=fuse_k, **eng_kw
         )
         return eng, trace
 
@@ -263,16 +266,94 @@ class TestEngineSharedPlan:
                 np.testing.assert_array_equal(_bits(x.best_dot), _bits(y.best_dot))
                 np.testing.assert_array_equal(x.n_candidates, y.n_candidates)
 
+    @staticmethod
+    def _count_calls(eng):
+        """Record (device calls, queries) as each round reports them."""
+        notes = []
+        note = eng.loop.note_device_dispatches
+
+        def counted(n, *a, **kw):
+            notes.append((n, kw.get("queries", 0)))
+            note(n, *a, **kw)
+
+        eng.loop.note_device_dispatches = counted
+        return notes
+
     def test_shared_bit_equal_and_fewer_dispatches(self):
         eng_off, trace = self._setup(shared_plan=False)
+        off_notes = self._count_calls(eng_off)
         res_off = eng_off.run(trace)
         eng_on, trace2 = self._setup(shared_plan=True, share_width=8)
+        on_notes = self._count_calls(eng_on)
         res_on = eng_on.run(trace2)
         self._assert_same_results(res_off, res_on)
-        off = eng_off.summary()["device_dispatches"]
-        on = eng_on.summary()["device_dispatches"]
-        assert on < off  # the whole point of the shared plan
+        # Off: one call a round.  On: one call per 8 of a round's queries.
+        assert len(off_notes) == eng_off.loop.dispatches
+        assert all(n == 1 for n, _ in off_notes)
+        assert len(on_notes) == eng_on.loop.dispatches
+        assert all(n == -(-q // 8) for n, q in on_notes)
+        assert any(q > 8 for _, q in on_notes)  # some round takes two calls
         assert 0.0 < eng_on.summary()["shared_batch_occupancy"] <= 1.0
+
+    @pytest.mark.parametrize("fuse_k", [1, 3])
+    def test_heterogeneous_predicates_one_call_a_round(self, fuse_k):
+        """Per-query radii and cuts with ``shared_plan`` off: every round is
+        one device call, with the results of the chunked shared plan."""
+        eng_off, trace = self._setup(fuse_k, shared_plan=False)
+        res_off = eng_off.run(trace)
+        eng_on, trace2 = self._setup(fuse_k, shared_plan=True, share_width=2)
+        res_on = eng_on.run(trace2)
+        self._assert_same_results(res_off, res_on)
+        loop = eng_off.loop
+        assert loop.dispatches > 0
+        assert loop.device_dispatches == loop.shared_calls == loop.dispatches
+        assert eng_on.loop.device_dispatches > eng_on.loop.dispatches
+
+    def test_indexed_plans_change_no_result_or_decision(self):
+        """A hybrid planner that sends resident buckets down the indexed
+        plan at the scan's cost moves neither residency nor cost: results,
+        hits, misses and the decision log equal the planner-less engine's,
+        and each round is still one call."""
+        import replay
+        from repro.core import HybridCostModel, HybridPlanner, JoinPlan
+
+        class IndexResident(HybridPlanner):
+            indexed = 0
+
+            def plan(self, queue_size, in_cache):
+                self.indexed += in_cache
+                return JoinPlan(
+                    "indexed" if in_cache else "scan",
+                    self.cost.batch_cost(queue_size, in_cache),
+                    queue_size, in_cache,
+                )
+
+        runs = []
+        for planner in (None, IndexResident(HybridCostModel(), 100)):
+            eng, trace = self._setup(
+                shared_plan=True, share_width=64, hybrid=planner,
+                cache_capacity=6,
+            )
+            rec = replay.TraceRecorder()
+            eng.loop.add_round_tap(rec)
+            # Two waves over the same sky: the second finds buckets
+            # resident, and the LRU order decides which.
+            for wave in range(2):
+                for q in trace:
+                    eng.submit(dataclasses.replace(
+                        q, query_id=q.query_id + 100 * wave,
+                        arrival_time=eng.sim_clock,
+                    ))
+                while eng.step() is not None:
+                    pass
+            runs.append((eng, eng.results, rec.entries))
+        (plain, res_plain, log_plain), (hyb, res_hyb, log_hyb) = runs
+        assert hyb.hybrid.indexed > 0
+        self._assert_same_results(res_plain, res_hyb)
+        assert replay.diff_traces(log_plain, log_hyb) == []
+        for a in ("hits", "misses"):
+            assert getattr(hyb.cache.stats, a) == getattr(plain.cache.stats, a)
+        assert hyb.loop.device_dispatches == hyb.loop.dispatches
 
     def test_width_one_chunking_still_bit_equal(self):
         """width < live queries: the executor chunks, results unchanged."""

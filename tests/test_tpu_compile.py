@@ -1,10 +1,10 @@
-"""Compile the cross-match cores for a TPU v5e that is described, not
-attached.
+"""Compile the cross-match core for a TPU v5e that is described, not
+attached, as each public entry point calls it.
 
 The TPU compiler is installed with JAX, so these tests refuse here what
 the chip's compiler would refuse (Mosaic layouts, VMEM limits) at no chip
 time.  They compile only; nothing runs, so they say nothing of results or
-speed.  Shapes are the cores' inputs after the host-side pow2 padding:
+speed.  Shapes are the core's inputs after the host-side pow2 padding:
 
 * the smallest: the pow2 floor of 8 rows on both operands;
 * the paper's bucket width: 10,000 objects -> 16,384 bucket rows, against
@@ -19,12 +19,11 @@ from __future__ import annotations
 import os
 
 import jax
-import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.crossmatch import ops as cm_ops
-from repro.kernels.crossmatch.kernel import COORD_PAD
 
 SMALLEST = (8, 8)  # (bucket rows, probe rows)
 PAPER = (16_384, 32_768)
@@ -63,27 +62,46 @@ def no_compile_cache():
     compilation_cache.reset_cache()
 
 
-def _spec(shape, one_chip):
-    return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+class _Built(Exception):
+    """Raised in place of the jitted core, carrying its array operands."""
 
 
-def _lower(core, shape, one_chip, use_pallas, band=None):
+def _built_operands(core, shape):
+    """The array operands the public entry point of ``core`` hands the one
+    jitted core for an ``(n bucket rows, m probe rows)`` call: every
+    entry point lowers ``_crossmatch_shared_jit``."""
     n, m = shape
-    bucket = _spec((n, COORD_PAD), one_chip)
-    probes = _spec((m, COORD_PAD), one_chip)
+    bucket, probes = np.zeros((n, 3), np.float32), np.zeros((m, 3), np.float32)
+    bseg, pseg = np.zeros(n, np.int32), np.zeros(m, np.int32)
+
+    def capture(*args):
+        raise _Built(args[:5])
+
+    real = cm_ops._crossmatch_shared_jit
+    cm_ops._crossmatch_shared_jit = capture
+    try:
+        if core == "single":
+            cm_ops.crossmatch(bucket, probes, COS_THR)
+        elif core == "fused":
+            cm_ops.crossmatch_fused(bucket, probes, bseg, pseg, COS_THR)
+        else:
+            thr = np.full(m, COS_THR, np.float32)
+            cm_ops.crossmatch_shared(bucket, probes, bseg, pseg, thr)
+    except _Built as built:
+        return built.args[0]
+    finally:
+        cm_ops._crossmatch_shared_jit = real
+    raise AssertionError(f"{core} never reached the jitted core")
+
+
+def _lower(core, shape, one_chip, use_pallas):
+    specs = [
+        jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+        for a in _built_operands(core, shape)
+    ]
     bm, bn = 128, 512  # the wrappers' defaults, which the engine uses
-    if core == "single":
-        return cm_ops._crossmatch_jit.lower(
-            bucket, probes, COS_THR, use_pallas, bm, bn, band, False
-        )
-    bseg, pseg = _spec((n,), one_chip), _spec((m,), one_chip)
-    if core == "fused":
-        return cm_ops._crossmatch_fused_jit.lower(
-            bucket, probes, bseg, pseg, COS_THR, use_pallas, bm, bn, False
-        )
-    thr = _spec((m,), one_chip)
     return cm_ops._crossmatch_shared_jit.lower(
-        bucket, probes, bseg, pseg, thr, use_pallas, bm, bn, False
+        *specs, use_pallas, bm, bn, False
     )
 
 
@@ -91,11 +109,6 @@ def _lower(core, shape, one_chip, use_pallas, band=None):
 @pytest.mark.parametrize("core", ["single", "fused", "shared"])
 def test_pallas_core_compiles(core, shape, one_chip, no_compile_cache):
     compiled = _lower(core, shape, one_chip, use_pallas=True).compile()
-    assert "tpu_custom_call" in compiled.as_text()
-
-
-def test_banded_kernel_compiles(one_chip, no_compile_cache):
-    compiled = _lower("single", PAPER, one_chip, True, band=2).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
 
