@@ -188,6 +188,13 @@ class WorkloadManager(SpillBookkeepingMixin):
         self.completed: dict[int, float] = {}  # query_id -> completion time
         self._listeners: list[Callable[[int], None]] = []
         self._spilled: set[int] = set()  # buckets with any spilled units
+        # Objects decomposed by each lookup, and those that overlap more
+        # than one bucket; ``decompose_counters`` (keys, range, spanning)
+        # mirrors them when observability is on.
+        self.objects_by_keys = 0
+        self.objects_by_range = 0
+        self.spanning_objects = 0
+        self.decompose_counters = None
 
     # -- change notification -------------------------------------------------
     def subscribe(self, fn: Callable[[int], None]) -> Callable[[int], None]:
@@ -207,27 +214,60 @@ class WorkloadManager(SpillBookkeepingMixin):
             fn(bucket_id)
 
     def _decompose(self, query: Query) -> dict[int, list[int]]:
-        per_bucket: dict[int, list[int]] = defaultdict(list)
-        if self._bucket_of_keys is not None and query.n_objects:
-            lo_b = self._bucket_of_keys(query.keys_lo)
-            hi_b = self._bucket_of_keys(query.keys_hi)
-            simple = lo_b == hi_b  # the common case: one bucket per object
-            idx = np.nonzero(simple)[0]
-            if len(idx):
-                order = idx[np.argsort(lo_b[idx], kind="stable")]
-                ub, starts = np.unique(lo_b[order], return_index=True)
-                for b, grp in zip(ub, np.split(order, starts[1:])):
-                    per_bucket[int(b)].extend(grp.tolist())
-            for i in np.nonzero(~simple)[0]:
-                for b in range(int(lo_b[i]), int(hi_b[i]) + 1):
-                    per_bucket[int(b)].append(int(i))
-            return per_bucket
-        for i in range(query.n_objects):
-            for b in self._bucket_of_range(
-                int(query.keys_lo[i]), int(query.keys_hi[i])
-            ):
-                per_bucket[int(b)].append(i)
+        """Bucket -> object indices, in one order whichever lookup the
+        caller supplied: buckets as each is first reached walking the
+        objects in index order (a spanning object reaches its buckets in
+        ascending id), each bucket's indices ascending.  With
+        ``bucket_of_keys`` the objects are mapped in one vectorised pass;
+        a caller with only ``bucket_of_range`` gets the per-object loop."""
+        n = query.n_objects
+        by_keys = self._bucket_of_keys is not None
+        if by_keys:
+            per_bucket, spanning = self._decompose_keys(query)
+            self.objects_by_keys += n
+        else:
+            per_bucket: dict[int, list[int]] = defaultdict(list)
+            spanning = 0
+            for i in range(n):
+                bs = self._bucket_of_range(
+                    int(query.keys_lo[i]), int(query.keys_hi[i])
+                )
+                spanning += len(bs) > 1
+                for b in bs:
+                    per_bucket[int(b)].append(i)
+            self.objects_by_range += n
+        self.spanning_objects += spanning
+        m = self.decompose_counters
+        if m is not None:
+            m[0 if by_keys else 1].inc(n)
+            m[2].inc(spanning)
         return per_bucket
+
+    def _decompose_keys(self, query: Query) -> tuple[dict[int, list[int]], int]:
+        """The vectorised map of ``_decompose``, and its spanning-object
+        count."""
+        lo_b = np.asarray(self._bucket_of_keys(query.keys_lo), np.int64)
+        hi_b = np.asarray(self._bucket_of_keys(query.keys_hi), np.int64)
+        span = np.maximum(hi_b - lo_b + 1, 0)
+        spanning = int(np.count_nonzero(span > 1))
+        # One (bucket, index) pair per bucket an object overlaps, in
+        # object order.
+        idx = np.repeat(np.arange(len(span)), span)
+        if not len(idx):
+            return {}, spanning
+        first = np.repeat(np.cumsum(span) - span, span)
+        bucket = np.repeat(lo_b, span) + (np.arange(len(idx)) - first)
+        # Group by bucket, indices ascending inside each (a stable sort of
+        # pairs already in index order), then order the groups by their
+        # lowest index, then by bucket id.
+        order = np.argsort(bucket, kind="stable")
+        bucket, idx = bucket[order], idx[order]
+        starts = np.flatnonzero(np.diff(bucket, prepend=bucket[0] - 1))
+        ends = np.append(starts[1:], len(idx))
+        groups = np.lexsort((bucket[starts], idx[starts]))
+        ids, idx = bucket[starts].tolist(), idx.tolist()
+        lo, hi = starts.tolist(), ends.tolist()
+        return {ids[g]: idx[lo[g]:hi[g]] for g in groups.tolist()}, spanning
 
     # -- intake -------------------------------------------------------------
     def decompose(self, query: Query) -> dict[int, list[int]]:

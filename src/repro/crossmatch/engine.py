@@ -100,6 +100,7 @@ class CrossMatchEngine:
         # bytes price the §6 overflow budget (CostModel.probe_bytes).
         self.wm = WorkloadManager(
             catalog.partitioner.buckets_for_range,
+            catalog.partitioner.bucket_of_keys,
             probe_bytes=self.cost_model.probe_bytes,
             min_unit_bytes=self.cost_model.min_unit_bytes,
         )
@@ -158,6 +159,7 @@ class CrossMatchEngine:
                 self.loop, track=0, clock="wall", h2d_bytes=cm_ops.h2d_bytes
             )
             self._m_frames = self.obs.bucket_frame_counters()
+            self.wm.decompose_counters = self.obs.decompose_counters()
 
     # -- loop-owned counters (kept as attributes for back-compat) --------------
     @property
@@ -588,9 +590,14 @@ class ShardedCrossMatch:
         # Router: decompose once, centrally; never services anything.
         self.router = WorkloadManager(
             catalog.partitioner.buckets_for_range,
+            catalog.partitioner.bucket_of_keys,
             probe_bytes=self.cost_model.probe_bytes,
             min_unit_bytes=self.cost_model.min_unit_bytes,
         )
+        if self.engines[0].obs is not None:
+            self.router.decompose_counters = (
+                self.engines[0].obs.decompose_counters()
+            )
         self._locks = [threading.Lock() for _ in range(self.n_shards)]
         self._steal_lock = threading.Lock()
         # Drain-thread fault channel: a thread that dies mid-drain records

@@ -152,6 +152,29 @@ class Observability:
             for outcome in ("reused", "allocated")
         )
 
+    def decompose_counters(self) -> tuple:
+        """The (keys, range) children of ``liferaft_decompose_objects_total``
+        and ``liferaft_decompose_spanning_objects_total``: query objects
+        mapped to buckets by the vectorised key lookup or the per-object
+        range loop, and those that overlap more than one bucket
+        (``WorkloadManager._decompose``)."""
+        reg = self.registry
+        by_path = tuple(
+            reg.counter(
+                "liferaft_decompose_objects_total",
+                "Query objects mapped to their buckets at intake, by lookup",
+                path=path,
+            )
+            for path in ("keys", "range")
+        )
+        return by_path + (
+            reg.counter(
+                "liferaft_decompose_spanning_objects_total",
+                "Query objects at intake whose key range overlaps more "
+                "than one bucket",
+            ),
+        )
+
     def note_steal(self, ev) -> None:
         """``on_steal`` tap: one work-steal migration (reads ``ev`` only)."""
         m = self._steal_m

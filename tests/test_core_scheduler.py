@@ -174,6 +174,60 @@ class TestWorkloadManager:
         assert ages[1] == pytest.approx(10_000.0)  # oldest request dominates
 
 
+    @pytest.mark.parametrize(
+        "case, seed",
+        [("random", s) for s in range(4)]
+        + [("span2", 10), ("span3plus", 11), ("boundaries", 12),
+           ("empty", 13), ("full_range", 14), ("full_range", 15)],
+    )
+    def test_keys_decompose_keeps_the_range_loop_order(self, case, seed):
+        """The vectorised key lookup gives the per-object range loop's
+        mapping element for element: buckets in first-insertion order,
+        each bucket's indices ascending, spanning objects included."""
+        rng = np.random.default_rng(seed)
+        keys = rng.integers(0, 2**32, int(rng.integers(500, 5_000)))
+        p = Partitioner(keys.astype(np.uint64),
+                        objects_per_bucket=int(rng.integers(50, 400)))
+        bound = np.array([s.key_lo for s in p.specs], np.int64)
+        top = p.specs[-1].key_hi
+        nb = p.n_buckets
+        n = int(rng.integers(50, 600))
+        lo = rng.integers(0, top, n)
+        hi = lo + rng.integers(0, 2**16, n)
+        pick = rng.random(n) < 0.3
+        b = rng.integers(0, nb - 1, n)
+        if case == "span2":  # the last key of b through the first of b+1
+            lo[pick], hi[pick] = bound[b[pick] + 1] - 1, bound[b[pick] + 1]
+        elif case == "span3plus":
+            far = np.minimum(b + rng.integers(2, 6, n), nb - 1)
+            lo[pick], hi[pick] = bound[b[pick]] + 1, bound[far[pick]] + 1
+        elif case == "boundaries":  # on the first and last buckets' edges
+            edge = np.array([0, bound[0], bound[0] + 1, bound[-1] - 1,
+                             bound[-1], top - 1, top, 2 * top])
+            lo, hi = rng.choice(edge, n), rng.choice(edge, n)
+            lo, hi = np.minimum(lo, hi), np.maximum(lo, hi)
+        elif case == "empty":
+            lo = hi = np.zeros(0, np.int64)
+        elif case == "full_range":
+            lo[n // 2], hi[n // 2] = 0, np.iinfo(np.int64).max
+        q = Query(0, 0.0, lo.astype(np.uint64), hi.astype(np.uint64))
+        by_keys = WorkloadManager(p.buckets_for_range, p.bucket_of_keys)
+        by_range = WorkloadManager(p.buckets_for_range)
+        got, want = by_keys._decompose(q), by_range._decompose(q)
+        assert list(got.items()) == list(want.items())
+        assert by_keys.objects_by_keys == by_range.objects_by_range == q.n_objects
+        assert by_keys.objects_by_range == by_range.objects_by_keys == 0
+        spanning = int(np.sum(p.bucket_of_keys(q.keys_hi)
+                              > p.bucket_of_keys(q.keys_lo)))
+        assert by_keys.spanning_objects == by_range.spanning_objects == spanning
+        if case in ("span2", "span3plus"):
+            widths = {len(p.buckets_for_range(int(a), int(z)))
+                      for a, z in zip(q.keys_lo, q.keys_hi)}
+            assert (2 in widths) if case == "span2" else max(widths) >= 3
+        if case == "full_range":
+            assert len(got) == nb and all(n // 2 in v for v in got.values())
+
+
 # ---------------------------------------------------------------- schedulers
 class TestSchedulers:
     def _setup(self):

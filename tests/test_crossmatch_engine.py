@@ -169,3 +169,48 @@ class TestEngineCorrectness:
                          [r.match_obj[0] for r in eng.results[q]]})
         assert buckets_serviced < 2 * max(per_query, 1) + 4
         assert eng.results[0] and eng.results[1]
+
+
+class TestIntake:
+    """Queries are decomposed by the partitioner's vectorised key lookup:
+    no per-object range call at intake, every object tallied as ``keys``."""
+
+    @pytest.mark.parametrize("sharded", [False, True])
+    def test_submit_never_calls_the_range_lookup(self, catalog, monkeypatch, sharded):
+        from repro.crossmatch import ShardedCrossMatch
+        from repro.obs import Observability
+
+        calls = []
+        real = catalog.partitioner.buckets_for_range
+
+        def counting(lo, hi):
+            calls.append((lo, hi))
+            return real(lo, hi)
+
+        monkeypatch.setattr(catalog.partitioner, "buckets_for_range", counting)
+        trace = make_trace(
+            catalog,
+            TraceConfig(n_queries=12, arrival_rate=2.0, objects_median=60, seed=3),
+        )
+        obs = Observability()
+        if sharded:
+            sx = ShardedCrossMatch(catalog, n_shards=2, cache_capacity=4, obs=obs)
+            wm = sx.router
+        else:
+            sx = CrossMatchEngine(catalog, match_radius_rad=2e-3, obs=obs)
+            wm = sx.wm
+        for q in trace:
+            sx.submit(q)
+        assert calls == []
+        assert wm.objects_by_keys == sum(q.n_objects for q in trace) > 0
+        assert wm.objects_by_range == 0
+        series = obs.snapshot()["metrics"]["liferaft_decompose_objects_total"]["series"]
+        assert {s["labels"]["path"]: s["value"] for s in series} == {
+            "keys": wm.objects_by_keys, "range": 0,
+        }
+        spanning = sum(
+            int(np.sum(catalog.partitioner.bucket_of_keys(q.keys_hi)
+                       > catalog.partitioner.bucket_of_keys(q.keys_lo)))
+            for q in trace
+        )
+        assert wm.spanning_objects == spanning

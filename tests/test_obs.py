@@ -522,6 +522,32 @@ def test_bucket_frame_counters_equal_the_engine_counts():
     assert eng.frames_allocated <= 3 + 2 < eng.frames_reused
 
 
+def test_decompose_counters_equal_the_manager_tallies():
+    """``liferaft_decompose_objects_total`` and
+    ``liferaft_decompose_spanning_objects_total`` mirror the workload
+    manager's tallies; the engine decomposes every object by key."""
+    from repro.crossmatch import CrossMatchEngine, TraceConfig, make_trace
+
+    catalog = _catalog()
+    trace = make_trace(
+        catalog,
+        TraceConfig(n_queries=20, arrival_rate=2.0, objects_median=40, seed=23),
+    )
+    obs = Observability()
+    eng = CrossMatchEngine(catalog, match_radius_rad=4e-3, obs=obs)
+    for q in trace:
+        eng.submit(q)
+    m = obs.snapshot()["metrics"]
+    got = {
+        s["labels"]["path"]: s["value"]
+        for s in m["liferaft_decompose_objects_total"]["series"]
+    }
+    assert got == {"keys": sum(q.n_objects for q in trace), "range": 0}
+    assert got["keys"] == eng.wm.objects_by_keys > 0
+    (spanning,) = m["liferaft_decompose_spanning_objects_total"]["series"]
+    assert spanning["value"] == eng.wm.spanning_objects
+
+
 def test_phase_spans_land_in_the_profiler_trace(tmp_path):
     """The spans are ``liferaft.*`` TraceAnnotations in the profiler's own
     trace: each round holds its select/launch/complete, each submit its
